@@ -85,9 +85,9 @@ chaos-soak:
 	JAX_PLATFORMS=cpu $(PY) benchmarks/chaos.py --seed 0 --trials 8 --holes 4
 
 # the DP-kernel promotion harness, check mode (scan vs Pallas v1 vs
-# rotband v2 bit-identity, interpret mode on CPU).  The timed three-arm
-# run that emits the decision record needs the real chip — it is step 4
-# of benchmarks/tpu_battery.sh, not a make target.
+# rotband v2 bit-identity, interpret mode on CPU).  On the chip, run
+# `python chip_smoke.py` (the main path, including the kernels'
+# byte-identity); the timed three-arm run needs the chip too.
 pallas-ab:
 	JAX_PLATFORMS=cpu $(PY) benchmarks/pallas_ab.py --mode check
 

@@ -135,7 +135,7 @@ def trial_kill_resume(in_fa: str, tmp: str, ref: bytes, point: str,
     out = os.path.join(tmp, f"o_kill_{point}.fa")
     jp = os.path.join(tmp, f"j_{point}.json")
     args = _base_args(in_fa, out, ("--journal", jp))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", CCSX_SKIP_PROBE="1",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="", CCSX_FAULTS=f"{point}@{n}",
                CCSX_JOURNAL_FSYNC_S="0")
     r = subprocess.run([sys.executable, "-c", _RUNNER, *args], env=env,

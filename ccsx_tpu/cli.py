@@ -829,7 +829,11 @@ def main(argv: Optional[list] = None) -> int:
     # initializes) and decide --batch auto from the resolved backend.
     from ccsx_tpu.utils.device import resolve_device
 
-    backend = resolve_device(cfg.device)
+    try:
+        backend = resolve_device(cfg.device)
+    except RuntimeError as e:       # --device tpu off a TPU, or init
+        print(f"Error: {e}", file=sys.stderr)
+        return 1
     batch = args.batch
     if batch == "auto":
         batch = "on" if backend == "tpu" else "off"

@@ -1,15 +1,20 @@
 """Native (C++) IO layer loader.
 
-Builds ``libccsx_io.so`` from io_native.cpp on first use if a compiler is
-present, loads it via ctypes, and exposes ``lib()``.  Import never fails:
-callers check ``available()`` and fall back to the pure-Python parsers
-(ccsx_tpu.io.fastx / ccsx_tpu.io.bam) when the toolchain is absent.
+Builds ``libccsx_io.so`` from the tracked sources in this directory on
+first use (``make``, under a file lock so concurrent processes do not
+race one build), loads it via ctypes, and exposes ``lib()``.  Callers
+check ``available()`` and fall back to the pure-Python parsers
+(ccsx_tpu.io.fastx / ccsx_tpu.io.bam) only when there is no compiler,
+and say so loudly.  A build that fails with a compiler present, or a
+built library that will not load, raises.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import shutil
 import subprocess
 import sys
 import threading
@@ -17,57 +22,43 @@ import threading
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libccsx_io.so")
 _LOG = os.path.join(_DIR, "build.log")
+_LOCK = os.path.join(_DIR, ".build.lock")
 _lock = threading.Lock()
 _lib = None
 _tried = False
 _build_error: "str | None" = None
 
 
-def _note_failure(summary: str, output: str) -> None:
-    """A failed/stale auto-rebuild used to be SILENT (the native path
-    just disappeared and ingest got mysteriously slow): persist the
-    compiler output, print one loud line with the path, and remember
-    the summary for Metrics (booked as native_build_error in every
-    metrics event)."""
+def _build() -> bool:
+    """Run ``make``; False (with one loud line, remembered for Metrics as
+    native_build_error) when there is no toolchain to run it with."""
     global _build_error
-    log_hint = ""
-    if output:
+    missing = [t for t in ("make", os.environ.get("CXX", "g++"))
+               if shutil.which(t) is None]
+    if missing:
+        _build_error = f"no {' / '.join(missing)} on PATH"
+        print(f"[ccsx-tpu] WARNING: cannot build the native IO library "
+              f"({_build_error}) — using the pure-Python parsers (same "
+              f"bytes, slower ingest)", file=sys.stderr)
+        return False
+    r = subprocess.run(["make", "-s", "-C", _DIR], check=False,
+                       capture_output=True, timeout=300, text=True)
+    if r.returncode != 0 or not os.path.exists(_SO):
+        out = (r.stdout or "") + (r.stderr or "")
         try:
             with open(_LOG, "w", encoding="utf-8") as f:
-                f.write(output)
-            log_hint = f"; compiler log: {_LOG}"
+                f.write(out)
         except OSError:
             pass
-    _build_error = summary
-    print(f"[ccsx-tpu] WARNING: native IO rebuild FAILED — falling back "
-          f"to the pure-Python parsers (same bytes, slower ingest): "
-          f"{summary}{log_hint}", file=sys.stderr)
-
-
-def _build() -> bool:
-    try:
-        r = subprocess.run(
-            ["make", "-s", "-C", _DIR],
-            check=False, capture_output=True, timeout=120, text=True,
-        )
-    except (OSError, subprocess.SubprocessError) as e:
-        _note_failure(f"{type(e).__name__}: {e}", "")
-        return False
-    if r.returncode != 0:
-        err = (r.stderr or r.stdout or "").strip()
-        first = next((ln for ln in err.splitlines() if ln.strip()),
-                     f"make rc {r.returncode}")
-        _note_failure(first[:200], (r.stdout or "") + (r.stderr or ""))
-        return False
-    if not os.path.exists(_SO):
-        _note_failure("make succeeded but libccsx_io.so is missing", "")
-        return False
+        raise RuntimeError(
+            f"native IO library build failed (make rc {r.returncode}; "
+            f"compiler log: {_LOG}): {out.strip()[-400:]}")
     return True
 
 
 def build_error() -> "str | None":
-    """One-line summary of a failed native auto-rebuild this process
-    observed (None when the native path loaded or was never needed).
+    """One-line summary of why this process could not build the native
+    library (None when the native path loaded or was never needed).
     Read by Metrics.snapshot() so every metrics event carries the
     degradation."""
     return _build_error
@@ -96,39 +87,26 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     ]
     lib.ccsx_error.restype = c.c_char_p
     lib.ccsx_error.argtypes = [c.c_void_p]
-    # filter accounting (guarded: a stale prebuilt .so without the
-    # symbols must degrade to "counts unavailable", not fail to load)
     for name in ("ccsx_filter_counts", "ccsx_prefetch_filter_counts"):
-        try:
-            fn = getattr(lib, name)
-        except AttributeError:
-            continue
+        fn = getattr(lib, name)
         fn.restype = None
         fn.argtypes = [c.c_void_p] + [c.POINTER(c.c_int64)] * 3
-    # salvage-mode ingest (same stale-.so guard: native/io.py falls
-    # back to the pure-Python salvage readers when these are absent)
-    try:
-        lib.ccsx_set_salvage.restype = None
-        lib.ccsx_set_salvage.argtypes = [c.c_void_p, c.c_int, c.c_int64]
-        lib.ccsx_prefetch_open_s.restype = c.c_void_p
-        lib.ccsx_prefetch_open_s.argtypes = [
-            c.c_char_p, c.c_int, c.c_int32, c.c_int64, c.c_int64,
-            c.c_int32, c.c_int, c.c_int64]
-        for name in ("ccsx_error_reason", "ccsx_prefetch_error_reason",
-                     "ccsx_corrupt_summary",
-                     "ccsx_prefetch_corrupt_summary"):
-            fn = getattr(lib, name)
-            fn.restype = c.c_char_p
-            fn.argtypes = [c.c_void_p]
-        for name in ("ccsx_corrupt_events",
-                     "ccsx_prefetch_corrupt_events",
-                     "ccsx_corrupt_exempt",
-                     "ccsx_prefetch_corrupt_exempt"):
-            fn = getattr(lib, name)
-            fn.restype = c.c_int64
-            fn.argtypes = [c.c_void_p]
-    except AttributeError:
-        pass
+    lib.ccsx_set_salvage.restype = None
+    lib.ccsx_set_salvage.argtypes = [c.c_void_p, c.c_int, c.c_int64]
+    lib.ccsx_prefetch_open_s.restype = c.c_void_p
+    lib.ccsx_prefetch_open_s.argtypes = [
+        c.c_char_p, c.c_int, c.c_int32, c.c_int64, c.c_int64,
+        c.c_int32, c.c_int, c.c_int64]
+    for name in ("ccsx_error_reason", "ccsx_prefetch_error_reason",
+                 "ccsx_corrupt_summary", "ccsx_prefetch_corrupt_summary"):
+        fn = getattr(lib, name)
+        fn.restype = c.c_char_p
+        fn.argtypes = [c.c_void_p]
+    for name in ("ccsx_corrupt_events", "ccsx_prefetch_corrupt_events",
+                 "ccsx_corrupt_exempt", "ccsx_prefetch_corrupt_exempt"):
+        fn = getattr(lib, name)
+        fn.restype = c.c_int64
+        fn.argtypes = [c.c_void_p]
     lib.ccsx_close.restype = None
     lib.ccsx_close.argtypes = [c.c_void_p]
     for name in ("ccsx_encode", "ccsx_revcomp_ascii", "ccsx_revcomp_codes"):
@@ -179,19 +157,28 @@ def lib():
         import glob
 
         srcs = glob.glob(os.path.join(_DIR, "*.cpp"))
-        if not os.path.exists(_SO) or any(
-            os.path.getmtime(_SO) < os.path.getmtime(s) for s in srcs
-        ):
-            if not _build():
-                return None
+        try:
+            lockf = open(_LOCK, "w")
+        except OSError:          # read-only install: nothing to build
+            lockf = None
+        try:
+            if lockf is not None:
+                fcntl.flock(lockf, fcntl.LOCK_EX)  # one build at a time
+            if not os.path.exists(_SO) or any(
+                os.path.getmtime(_SO) < os.path.getmtime(s) for s in srcs
+            ):
+                if not _build():
+                    return None
+        finally:
+            if lockf is not None:
+                lockf.close()
         try:
             _lib = _bind(ctypes.CDLL(_SO))
         except OSError as e:
-            # a built .so that will not load (e.g. a leftover TSAN/ASAN
-            # instrumented build, static-TLS failures) is the same
-            # silent degradation as a failed compile — say so
-            _note_failure(f"libccsx_io.so failed to load: {e}", "")
-            _lib = None
+            # e.g. a leftover TSAN/ASAN instrumented build
+            raise RuntimeError(
+                f"libccsx_io.so failed to load ({e}); rebuild it with "
+                f"`make -C {_DIR} clean all`") from e
     return _lib
 
 

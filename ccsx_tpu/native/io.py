@@ -30,20 +30,10 @@ class NativeStreamError(CorruptionError):
         super().__init__(reason or "bam_bad_record", msg)
 
 
-def salvage_supported() -> bool:
-    """True when the loaded native library exports the salvage entry
-    points (a stale prebuilt .so degrades to the Python salvage
-    readers, never to a load failure)."""
-    L = native.lib()
-    return L is not None and hasattr(L, "ccsx_set_salvage") \
-        and hasattr(L, "ccsx_prefetch_open_s")
-
-
 def _reason(L, h, fn_name: str) -> str:
-    fn = getattr(L, fn_name, None)
-    if fn is None:
+    if not fn_name:
         return ""
-    val = fn(h)
+    val = getattr(L, fn_name)(h)
     return val.decode() if val else ""
 
 
@@ -93,13 +83,12 @@ def stream_zmws_native(path: str, cfg: CcsConfig,
     L, h = _open(path, cfg.is_bam)
     L.ccsx_set_filter(h, cfg.min_pass_count, cfg.min_subread_len,
                       cfg.max_subread_len)
-    if hasattr(L, "ccsx_set_salvage"):
-        # the --max-record-bytes allocation bound applies salvage ON OR
-        # OFF; on=1 additionally enables the resync behavior
-        L.ccsx_set_salvage(h, 1 if getattr(cfg, "salvage", False) else 0,
-                           getattr(cfg, "max_record_bytes", 0) or 0)
+    # the --max-record-bytes allocation bound applies salvage ON OR
+    # OFF; on=1 additionally enables the resync behavior
+    L.ccsx_set_salvage(h, 1 if getattr(cfg, "salvage", False) else 0,
+                       getattr(cfg, "max_record_bytes", 0) or 0)
     return _zmw_gen(h, cfg, L.ccsx_next_zmw, L.ccsx_error, L.ccsx_close,
-                    counts_fn=getattr(L, "ccsx_filter_counts", None),
+                    counts_fn=L.ccsx_filter_counts,
                     metrics=metrics, reason_fn_name="ccsx_error_reason",
                     corrupt_fns=("ccsx_corrupt_events",
                                  "ccsx_corrupt_summary"))
@@ -172,10 +161,10 @@ def _zmw_gen(h, cfg: CcsConfig, next_fn, error_fn, close_fn,
     lens = c.POINTER(c.c_int32)()
     n = c.c_int32()
     excluded = 0
-    events_fn = getattr(L, corrupt_fns[0], None) \
+    events_fn = getattr(L, corrupt_fns[0]) \
         if getattr(cfg, "salvage", False) and corrupt_fns[0] else None
-    exempt_fn = getattr(L, corrupt_fns[0].replace("_events", "_exempt"),
-                        None) if events_fn is not None else None
+    exempt_fn = getattr(L, corrupt_fns[0].replace("_events", "_exempt")) \
+        if events_fn is not None else None
     corrupt_seen = 0
     exempt_seen = 0
 
@@ -190,7 +179,7 @@ def _zmw_gen(h, cfg: CcsConfig, next_fn, error_fn, close_fn,
         if events_fn is None:
             return
         ev = int(events_fn(h))
-        ex = int(exempt_fn(h)) if exempt_fn is not None else 0
+        ex = int(exempt_fn(h))
         if ev > corrupt_seen:
             if metrics is not None:
                 metrics.bump(holes_corrupt=ev - corrupt_seen)
@@ -250,24 +239,18 @@ def stream_zmws_prefetch(path: str, cfg: CcsConfig,
     L = native.lib()
     if L is None:
         raise RuntimeError("native IO library unavailable")
-    if hasattr(L, "ccsx_prefetch_open_s"):
-        # the salvage-capable open also carries the --max-record-bytes
-        # bound, which applies salvage on or off
-        h = L.ccsx_prefetch_open_s(
-            path.encode(), 1 if cfg.is_bam else 0, cfg.min_pass_count,
-            cfg.min_subread_len, cfg.max_subread_len, queue_cap,
-            1 if getattr(cfg, "salvage", False) else 0,
-            getattr(cfg, "max_record_bytes", 0) or 0)
-    else:
-        h = L.ccsx_prefetch_open(path.encode(), 1 if cfg.is_bam else 0,
-                                 cfg.min_pass_count, cfg.min_subread_len,
-                                 cfg.max_subread_len, queue_cap)
+    # the salvage-capable open also carries the --max-record-bytes
+    # bound, which applies salvage on or off
+    h = L.ccsx_prefetch_open_s(
+        path.encode(), 1 if cfg.is_bam else 0, cfg.min_pass_count,
+        cfg.min_subread_len, cfg.max_subread_len, queue_cap,
+        1 if getattr(cfg, "salvage", False) else 0,
+        getattr(cfg, "max_record_bytes", 0) or 0)
     if not h:
         raise OSError(f"cannot open {path!r}")
     return _zmw_gen(h, cfg, L.ccsx_prefetch_next, L.ccsx_prefetch_error,
                     L.ccsx_prefetch_close,
-                    counts_fn=getattr(L, "ccsx_prefetch_filter_counts",
-                                      None),
+                    counts_fn=L.ccsx_prefetch_filter_counts,
                     metrics=metrics,
                     reason_fn_name="ccsx_prefetch_error_reason",
                     corrupt_fns=("ccsx_prefetch_corrupt_events",

@@ -36,22 +36,14 @@ from ccsx_tpu.config import AlignParams
 from ccsx_tpu.ops import banded, traceback
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map across the 0.4.x/0.6+ API split: the entry point
-    moved from jax.experimental.shard_map to jax.shard_map and the
-    replication check was renamed check_rep -> check_vma.  Both the
-    (data, pass) sharded round below and the fused multi-chip packed
-    dispatch (pipeline/batch.py) go through here, with the check
-    disabled for the same reason: DP scan carries mix replicated init
-    constants with varying values, and pcasting every carry component
-    buys nothing."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+def shard_map_nocheck(f, mesh: Mesh, in_specs, out_specs):
+    """jax.shard_map with the replication check off.  Both the (data,
+    pass) sharded round below and the fused multi-chip packed dispatch
+    (pipeline/batch.py) go through here: DP scan carries mix replicated
+    init constants with varying values, and pcasting every carry
+    component buys nothing."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def build_slab_mesh(devices) -> Mesh:
@@ -140,7 +132,7 @@ def make_sharded_round(mesh: Mesh, params: AlignParams, tmax: int,
     out_specs = (P("data", None), P("data", None, None),
                  P("data", None, None), P("data", None),
                  P("data", None))
-    shard = shard_map_compat(local_round, mesh, in_specs, out_specs)
+    shard = shard_map_nocheck(local_round, mesh, in_specs, out_specs)
     return jax.jit(shard)
 
 

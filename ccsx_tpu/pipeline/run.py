@@ -49,13 +49,9 @@ def open_zmw_stream(path: str, cfg: CcsConfig, metrics=None):
 
     salvage = bool(getattr(cfg, "salvage", False))
     if path != "-" and native.available():
-        from ccsx_tpu.native.io import (salvage_supported,
-                                        stream_zmws_prefetch)
+        from ccsx_tpu.native.io import stream_zmws_prefetch
 
-        if not salvage or salvage_supported():
-            return stream_zmws_prefetch(path, cfg, metrics=metrics)
-        # stale prebuilt .so without the salvage entry points: fall
-        # through to the pure-Python salvage readers
+        return stream_zmws_prefetch(path, cfg, metrics=metrics)
     sink = SalvageSink(metrics, getattr(cfg, "max_record_bytes", 0)) \
         if salvage else None
     if cfg.is_bam:

@@ -57,8 +57,7 @@ from typing import Dict, List, Optional
 
 from ccsx_tpu import exitcodes
 
-# the subprocess runner body; a PRELUDE (backend pinning for tests /
-# CPU-forced environments) may be prepended
+# the subprocess runner body (children inherit JAX_PLATFORMS)
 _RUNNER = ("import sys; from ccsx_tpu.cli import main; "
            "sys.exit(main(sys.argv[1:]))")
 
@@ -68,17 +67,47 @@ _SHEPHERD_FLAGS = ("--max-rank-restarts", "--rank-backoff",
                    "--lease-timeout", "--join")
 
 
-def default_prelude() -> str:
-    """Backend pinning for the rank runners: when this process is
-    itself forced onto CPU (JAX_PLATFORMS=cpu — the test suite, `make
-    chaos`, CI), the ranks must be too; some accelerator plugins
-    override the env var at import time, so the pin must be an explicit
-    jax.config call before the CLI imports (the same idiom as
-    tests/test_faults._run_cli_subprocess)."""
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
-        return ("import jax; "
-                "jax.config.update('jax_platforms', 'cpu'); ")
-    return ""
+def local_tpu_chips() -> int:
+    """TPU chips this host lets a process open, counted without touching
+    JAX: the chips' device nodes (``/dev/accel<n>``, or ``/dev/vfio/<n>``
+    where the chips are bound to VFIO), capped by the TPU functions on
+    the PCI bus — a container may see every chip on the bus but be given
+    the nodes of only some."""
+    import glob
+
+    from jax._src import hardware_utils
+
+    pci, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    nodes = (glob.glob("/dev/accel[0-9]*")
+             or glob.glob("/dev/vfio/[0-9]*"))
+    return min(pci, len(nodes)) if nodes else pci
+
+
+def one_chip_envs(n: int, base_env: dict) -> Optional[List[dict]]:
+    """Env additions giving each of ``n`` JAX children one TPU chip of
+    its own, through libtpu's per-process chip visibility — or None when
+    the children would outnumber the host's chips (the caller refuses:
+    two processes cannot share a chip).  Children pinned to the CPU
+    (``JAX_PLATFORMS=cpu``), and hosts with no TPU chip, get no
+    additions.  Chips are counted from PCI, so this parent never
+    initialises JAX (it would hold the chips its children need)."""
+    if base_env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return [{} for _ in range(n)]
+    chips = local_tpu_chips()
+    if chips == 0:
+        return [{} for _ in range(n)]
+    if n > chips:
+        print(f"Error: {n} JAX children but {chips} local TPU chips; "
+              "each child needs a chip of its own", file=sys.stderr)
+        return None
+    # each child is a one-chip slice of its own; the lock file that
+    # keeps two processes off one chip is per host, so it is lifted for
+    # children whose visible chips are disjoint
+    return [{"TPU_VISIBLE_CHIPS": str(i),
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_PORT": str(8476 + i),
+             "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"} for i in range(n)]
 
 
 def strip_shepherd_flags(argv: List[str],
@@ -188,8 +217,7 @@ def shepherd_run(in_path: str, out_path: str, hosts: int,
                  env: Optional[dict] = None,
                  first_launch_env: Optional[Dict[int, dict]] = None,
                  poll_s: float = 0.25,
-                 merge: bool = True,
-                 runner_prelude: Optional[str] = None) -> int:
+                 merge: bool = True) -> int:
     """Supervise a sharded run end to end; returns a process rc
     (exitcodes.py: 0 = merged, 1 = a rank exhausted its restarts or
     the merge was refused).
@@ -205,9 +233,10 @@ def shepherd_run(in_path: str, out_path: str, hosts: int,
         print("Error: shepherd needs --hosts >= 1", file=sys.stderr)
         return exitcodes.RC_FATAL
     base_env = dict(os.environ if env is None else env)
-    prelude = (default_prelude() if runner_prelude is None
-               else runner_prelude)
     first_launch_env = first_launch_env or {}
+    chip_env = one_chip_envs(hosts, base_env)
+    if chip_env is None:
+        return exitcodes.RC_FATAL
     # a journal is what makes a restart a resume; inject one when the
     # caller didn't ask for their own
     fwd = list(forward_args)
@@ -218,7 +247,7 @@ def shepherd_run(in_path: str, out_path: str, hosts: int,
         journal = fwd[fwd.index("--journal") + 1]
 
     def launch(st: _Rank) -> None:
-        e = dict(base_env)
+        e = dict(base_env, **chip_env[st.rank])
         rank_fwd = fwd
         if st.attempts == 0 and not st.preempted:
             e.update(first_launch_env.get(st.rank, {}))
@@ -229,7 +258,7 @@ def shepherd_run(in_path: str, out_path: str, hosts: int,
             e.pop("CCSX_FAULTS", None)
             rank_fwd = strip_shepherd_flags(fwd,
                                             flags=("--inject-faults",))
-        cmd = [sys.executable, "-c", prelude + _RUNNER, *rank_fwd,
+        cmd = [sys.executable, "-c", _RUNNER, *rank_fwd,
                "--host-id", str(st.rank)]
         log_path = f"{out_path}.shard{st.rank}.log"
         try:
@@ -440,8 +469,7 @@ def fleet_run(in_path: str, out_path: str, cfg, hosts: int,
               env: Optional[dict] = None,
               first_launch_env: Optional[Dict[int, dict]] = None,
               poll_s: float = 0.25,
-              merge: bool = True,
-              runner_prelude: Optional[str] = None) -> int:
+              merge: bool = True) -> int:
     """The elastic scheduler (`ccsx-tpu shepherd --fleet-ranges M`):
     split the input into M >> N leased ranges (pipeline/fleet.py),
     launch ``hosts`` pull workers, and supervise the QUEUE rather than
@@ -475,9 +503,10 @@ def fleet_run(in_path: str, out_path: str, cfg, hosts: int,
         print("Error: fleet needs --hosts >= 1", file=sys.stderr)
         return exitcodes.RC_FATAL
     base_env = dict(os.environ if env is None else env)
-    prelude = (default_prelude() if runner_prelude is None
-               else runner_prelude)
     first_launch_env = dict(first_launch_env or {})
+    chip_env = one_chip_envs(hosts, base_env)
+    if chip_env is None:
+        return exitcodes.RC_FATAL
     try:
         n_holes = count_raw_holes(in_path, cfg)
     except (OSError, RuntimeError, ValueError) as e:
@@ -514,7 +543,7 @@ def fleet_run(in_path: str, out_path: str, cfg, hosts: int,
     expiry_seq = 0
 
     def launch(w: _Rank) -> None:
-        e = dict(base_env)
+        e = dict(base_env, **chip_env[w.rank])
         wf = worker_fwd
         if w.attempts == 0 and not w.preempted:
             e.update(first_launch_env.get(w.rank, {}))
@@ -523,7 +552,7 @@ def fleet_run(in_path: str, out_path: str, cfg, hosts: int,
             wf = strip_shepherd_flags(worker_fwd,
                                       flags=("--inject-faults",))
         name = f"w{w.rank}"
-        cmd = [sys.executable, "-c", prelude + _RUNNER, *wf,
+        cmd = [sys.executable, "-c", _RUNNER, *wf,
                "--fleet-dir", d, "--fleet-worker", name]
         log_path = f"{out_path}.fleet.{name}.log"
         banner = (f"\n=== fleet launch worker {name} attempt "
@@ -692,8 +721,7 @@ def fleet_run(in_path: str, out_path: str, cfg, hosts: int,
 
 def fleet_join(d: str, hosts: int,
                env: Optional[dict] = None,
-               poll_s: float = 0.25,
-               runner_prelude: Optional[str] = None) -> int:
+               poll_s: float = 0.25) -> int:
     """`ccsx-tpu shepherd --join <out>.fleet --hosts K`: add K pull
     workers to a RUNNING fleet mid-run.  Subordinate by design — the
     primary scheduler owns expiry and the merge; a joiner just pulls
@@ -708,20 +736,22 @@ def fleet_join(d: str, hosts: int,
               "running? start one with --fleet-ranges)", file=sys.stderr)
         return exitcodes.RC_FATAL
     base_env = dict(os.environ if env is None else env)
-    prelude = (default_prelude() if runner_prelude is None
-               else runner_prelude)
+    chip_env = one_chip_envs(hosts, base_env)
+    if chip_env is None:
+        return exitcodes.RC_FATAL
     out_path = state["output"]
     procs = []
     logs = []
     for k in range(hosts):
         name = f"j{os.getpid()}w{k}"
-        cmd = [sys.executable, "-c", prelude + _RUNNER,
+        cmd = [sys.executable, "-c", _RUNNER,
                *state.get("forward", []),
                "--fleet-dir", d, "--fleet-worker", name]
         log_path = f"{out_path}.fleet.{name}.log"
         banner = (f"\n=== fleet join worker {name} @ "
                   f"{time.strftime('%H:%M:%S')} ===\n")
-        proc, log = _spawn_worker(cmd, base_env, log_path, banner)
+        proc, log = _spawn_worker(cmd, dict(base_env, **chip_env[k]),
+                                  log_path, banner)
         procs.append(proc)
         logs.append(log)
         print(f"[ccsx-tpu] fleet: joined worker {name} (pid "
@@ -762,8 +792,7 @@ def serve_fleet_run(spool: str, n: int, serve_args: List[str],
                     gateway_port: int = 0,
                     env: Optional[dict] = None,
                     poll_s: float = 0.25,
-                    drain_grace_s: float = 30.0,
-                    runner_prelude: Optional[str] = None) -> int:
+                    drain_grace_s: float = 30.0) -> int:
     """`ccsx-tpu shepherd --serve-replicas N ...serve flags...`: run N
     warm serve replicas over ONE job spool (the lease domain,
     pipeline/gateway.py), optionally fronted by the thin gateway.
@@ -792,8 +821,10 @@ def serve_fleet_run(spool: str, n: int, serve_args: List[str],
         print("Error: --serve-replicas needs N >= 1", file=sys.stderr)
         return exitcodes.RC_FATAL
     base_env = dict(os.environ if env is None else env)
-    prelude = (default_prelude() if runner_prelude is None
-               else runner_prelude)
+    # the gateway never touches JAX; only the replicas take chips
+    chip_env = one_chip_envs(n, base_env)
+    if chip_env is None:
+        return exitcodes.RC_FATAL
     try:
         os.makedirs(spool, exist_ok=True)
     except OSError as e:
@@ -802,19 +833,20 @@ def serve_fleet_run(spool: str, n: int, serve_args: List[str],
         return exitcodes.RC_FATAL
 
     def launch(w: _Rank) -> None:
+        env = dict(base_env)
         if w.rank < 0:    # the gateway child
             name = "gateway"
-            cmd = [sys.executable, "-c", prelude + _RUNNER, "gateway",
+            cmd = [sys.executable, "-c", _RUNNER, "gateway",
                    "--spool", spool, "--port", str(gateway_port)]
         else:
             name = f"s{w.rank}"
-            cmd = [sys.executable, "-c", prelude + _RUNNER, "serve",
+            cmd = [sys.executable, "-c", _RUNNER, "serve",
                    *serve_args, "--replica-name", name]
+            env.update(chip_env[w.rank])
         log_path = os.path.join(spool, f"{name}.log")
         banner = (f"\n=== serve-fleet launch {name} attempt "
                   f"{w.attempts} @ {time.strftime('%H:%M:%S')} ===\n")
-        w.proc, w.log = _spawn_worker(cmd, dict(base_env), log_path,
-                                      banner)
+        w.proc, w.log = _spawn_worker(cmd, env, log_path, banner)
         w.relaunch_at = None
         print(f"[ccsx-tpu] serve-fleet: {name} up (pid {w.proc.pid}, "
               f"attempt {w.attempts}, log {log_path})", file=sys.stderr)

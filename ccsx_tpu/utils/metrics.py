@@ -265,15 +265,15 @@ class Metrics:
     device_dispatches: int = 0
     # per-implementation banded DP-fill attribution (consensus/star.
     # banded_impl dispatch): {"scan"|"pallas"|"rotband": dispatches}.
-    # Makes an A/B run or a breaker/compile-forced scan pin visible in
-    # top/stats//metrics (ccsx_banded_impl{impl=...}) without logs —
+    # Makes an A/B run visible in top/stats//metrics (ccsx_banded_impl{impl=...}) without logs —
     # bumped at the round/refine/packed dispatch sites via bump_banded()
     banded_dispatches: dict = dataclasses.field(default_factory=dict)
     refine_overflows: int = 0  # fused windows replayed on host (rare)
     # fault-tolerance ladder counters (pipeline/batch.py recovery):
-    # group bisections after a device OOM, per-request host replays
-    # (ladder bottom / data errors), and scan-spec pins after a Pallas
-    # compile failure (at most 1/process)
+    # group bisections after a device OOM and per-request host replays
+    # (ladder bottom / data errors).  compile_fallbacks stays 0 since a
+    # Pallas compile failure became fatal; it is kept in the metrics
+    # schema, where dashboards and chip_smoke.py read it
     oom_resplits: int = 0
     host_fallbacks: int = 0
     compile_fallbacks: int = 0
@@ -282,7 +282,7 @@ class Metrics:
     # and the backend circuit breaker's state machine — trips (closed ->
     # open on N strikes in the window), half-open probes, the live
     # state string, and a bounded log of the qualifying strikes
-    # (hang / compile / oom ladder-bottom, each {ts, kind, group})
+    # (hang / oom ladder-bottom, each {ts, kind, group})
     # prior sessions' emitted holes, restored from the journal on
     # resume (internal: feeds the --max-failed-holes fraction
     # denominator only — holes_out stays THIS session's emission count

@@ -170,10 +170,14 @@ def make_fasta(zmws: List[SynthZmw]) -> str:
 
 
 def identity(a: np.ndarray, b: np.ndarray) -> float:
-    """Global-alignment identity between two code sequences (oracle-based)."""
+    """Global-alignment identity between two code sequences: the oracle's
+    DP, run by the native scalar aligner (differential-tested equal to
+    ops/oracle.align, and the only one that fits 20 kb pairs) when it
+    is built, else by the NumPy oracle itself."""
+    from ccsx_tpu.native.align import align_scalar_native
     from ccsx_tpu.ops import oracle
 
-    rs = oracle.align(a, b, mode="global")
+    rs = align_scalar_native(a, b) or oracle.align(a, b, mode="global")
     return rs.identity
 
 

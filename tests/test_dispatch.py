@@ -89,6 +89,25 @@ def test_warmup_compiler_claim_semantics():
         wc.close()
 
 
+def test_warmup_build_failure_reraised_at_settle():
+    """A builder that fails to compile is not swallowed: the dispatch
+    that settles its key re-raises the build's own error."""
+    wc = WarmupCompiler(debounce_s=0.0)
+    try:
+        def boom():
+            raise RuntimeError("Mosaic failed to compile (injected)")
+
+        wc.submit("bad", boom, urgent=True)
+        wc.submit("good", lambda: None, urgent=True)
+        assert wc.drain(timeout=10)
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            wc.settle("bad")
+        wc.settle("good")
+        wc.settle("never-submitted")
+    finally:
+        wc.close()
+
+
 def test_warmup_urgent_jumps_debouncing_queue():
     """An urgent (sweep-time exact) job must not wait behind a still-
     debouncing prediction at the FIFO head — its dispatch is imminent
@@ -447,31 +466,6 @@ def test_bench_vs_prev_dp_kernel_gate(monkeypatch):
     bench.compare_dp_kernel(line, None, vp, reg)
     assert vp["dp_kernel"]["prev_source"] == "pallas_ab_tpu_r06.json"
     assert any("dp-kernel" in r for r in reg)
-
-
-def test_bench_device_attempt_report(tmp_path):
-    """A degraded CPU-fallback artifact must carry the failed device
-    attempt's stall diagnostics: the watchdog's last in-flight shape
-    group and a pointer to the persisted stderr report."""
-    bench = _bench_mod()
-    err = ("noise\n"
-           "[ccsx-tpu] STALL WATCHDOG: device dispatch 'refine_packed' "
-           "group='packed:q512:t1024:i2' open for 130.2s (> 120s stall "
-           "budget) — dumping state\n"
-           "stacks...\n"
-           "[ccsx-tpu] STALL WATCHDOG: device dispatch 'materialize' "
-           "group='packed:q1024:t1536:i2' open for 250.0s (> 120s "
-           "stall budget) — dumping state\n")
-    rp = tmp_path / "stall.txt"
-    rep = bench.device_attempt_report(err, report_path=str(rp))
-    assert rep["stall_dumps"] == 2
-    assert rep["last_inflight_group"] == "packed:q1024:t1536:i2"
-    assert rp.read_text().startswith("noise")
-    assert rep["stall_report"] and "stall.txt" in rep["stall_report"]
-    # no stderr at all (e.g. an instant spawn failure): still a report
-    empty = bench.device_attempt_report("")
-    assert empty == {"stall_report": None, "last_inflight_group": None,
-                     "stall_dumps": 0}
 
 
 # ---- CI compile-budget guard (the r7 storm, pinned) ------------------------

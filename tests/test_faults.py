@@ -134,18 +134,6 @@ def test_cli_rejects_bad_fault_spec(tmp_path, capsys):
     assert "--inject-faults" in capsys.readouterr().err
 
 
-def test_force_scan_fallback_is_one_time():
-    from ccsx_tpu.consensus import star
-
-    assert star._FORCE_SCAN is False
-    try:
-        assert star.force_scan_fallback("test reason") is True
-        assert star.use_pallas() is False          # even if env asks for it
-        assert star.force_scan_fallback("again") is False
-    finally:
-        star._FORCE_SCAN = False
-
-
 def test_journal_v1_still_accepted(tmp_path):
     """Legacy journals (no version/offsets) keep their cursor and skip
     the v2 verifications."""
@@ -229,34 +217,71 @@ def test_persistent_oom_falls_back_to_host(corpus, tmp_path, capsys):
     assert final["holes_out"] == 3 and final["holes_failed"] == 0
 
 
-def test_compile_failure_pins_scan_and_retries(corpus, tmp_path, capsys,
-                                               monkeypatch):
-    """A Pallas/Mosaic-looking compile failure forces the scan spec
-    (one-time) and retries the same group — no output change, no
-    aborted run."""
-    from ccsx_tpu.consensus import star
+def test_compile_failure_fails_the_run(corpus, tmp_path, capsys,
+                                       monkeypatch):
+    """A Pallas/Mosaic-looking compile failure is a program error: the
+    run ends non-zero — no quiet switch to the scan, no host replay —
+    and compile_fallbacks is never booked."""
     from ccsx_tpu.pipeline import batch as batch_mod
 
-    fa, ref = corpus
-    calls = {"n": 0}
+    fa, _ = corpus
 
     def fake_fire(point):
         if point == "device_oom":
-            calls["n"] += 1
-            if calls["n"] == 1:
-                raise RuntimeError("Mosaic lowering failed (injected)")
+            raise RuntimeError("Mosaic lowering failed (injected)")
 
     monkeypatch.setattr(batch_mod.faultinject, "fire", fake_fire)
-    assert star._FORCE_SCAN is False
-    out = tmp_path / "o.fa"
-    try:
-        assert cli.main(["-A", "-m", "1000", "--batch", "on",
-                         str(fa), str(out)]) == 0
-        assert star._FORCE_SCAN is True
-    finally:
-        star._FORCE_SCAN = False
-    assert out.read_bytes() == ref.read_bytes()
-    assert "falling back to the banded-scan spec" in capsys.readouterr().err
+    out, m = tmp_path / "o.fa", tmp_path / "m.jsonl"
+    with pytest.raises(RuntimeError, match="Mosaic"):  # a process: rc 1
+        cli.main(["-A", "-m", "1000", "--batch", "on", "--metrics",
+                  str(m), str(fa), str(out)])
+    assert "replaying on the host path" not in capsys.readouterr().err
+    events = [json.loads(line) for line in m.read_text().splitlines()]
+    assert events and events[-1]["event"] == "final"
+    assert all(e.get("compile_fallbacks", 0) == 0 for e in events)
+    assert all(e.get("host_fallbacks", 0) == 0 for e in events)
+
+
+def test_resolve_device_tpu_raises_on_cpu():
+    from ccsx_tpu.utils import device
+
+    with pytest.raises(RuntimeError, match="tpu"):
+        device.resolve_device("tpu")
+
+
+def test_resolve_device_auto_starts_no_subprocess(monkeypatch):
+    """auto is whatever backend JAX initialises, in this process: no
+    out-of-process probe."""
+    from ccsx_tpu.utils import device
+
+    def no_spawn(*a, **k):
+        raise AssertionError("resolve_device started a subprocess")
+
+    monkeypatch.setattr(subprocess, "run", no_spawn)
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    assert device.resolve_device("auto") == "cpu"
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR is honoured (and left to JAX: nothing
+    is set in code); unset, the cache is <checkout>/.jax_cache."""
+    import jax
+
+    from ccsx_tpu.utils import device
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert device.enable_compile_cache() == str(tmp_path)
+        assert updates == []
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(_REPO, ".jax_cache")
+        assert device.enable_compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
 
 
 # ---------- journal v2: crash-safe resume ----------
@@ -267,7 +292,7 @@ def _run_cli_subprocess(args, env_extra):
     tests/test_distributed.py."""
     runner = ("import sys, jax; jax.config.update('jax_platforms', 'cpu'); "
               "from ccsx_tpu.cli import main; sys.exit(main(sys.argv[1:]))")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", CCSX_SKIP_PROBE="1",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="", **env_extra)
     return subprocess.run([sys.executable, "-c", runner, *args], env=env,
                           cwd=_REPO, capture_output=True, text=True,

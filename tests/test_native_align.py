@@ -62,8 +62,8 @@ def test_alt_scores(rng):
 
 def test_size_cap_returns_none():
     from ccsx_tpu.native.align import align_scalar_native
-    q = np.zeros(1 << 14, np.uint8)
-    t = np.zeros(1 << 13, np.uint8)
+    q = np.zeros(1 << 15, np.uint8)
+    t = np.zeros(1 << 14, np.uint8)
     assert align_scalar_native(q, t) is None
 
 

@@ -86,7 +86,7 @@ def test_parity_cli_smoke(stub_bin, tmp_path):
         [sys.executable, os.path.join(_REPO, "benchmarks", "parity.py"),
          "--ccsx", stub_bin, "--holes", "2", "--configs", "1",
          "--json", str(out)],
-        env=dict(os.environ, JAX_PLATFORMS="cpu", CCSX_SKIP_PROBE="1"),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         cwd=_REPO, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert json.loads(out.read_text())["mean_identity_cross"] == 1.0

@@ -205,7 +205,7 @@ def test_pair_gate_host_replay_failure_quarantines(corpus, tmp_path,
 def _run_cli_subprocess(args, env_extra):
     runner = ("import sys, jax; jax.config.update('jax_platforms', 'cpu'); "
               "from ccsx_tpu.cli import main; sys.exit(main(sys.argv[1:]))")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", CCSX_SKIP_PROBE="1",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="", **env_extra)
     return subprocess.run([sys.executable, "-c", runner, *args], env=env,
                           cwd=_REPO, capture_output=True, text=True,

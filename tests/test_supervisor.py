@@ -31,11 +31,42 @@ def test_strip_shepherd_flags():
         "-A", "in.fa", "out.fa", "--hosts", "2"]
 
 
-def test_default_prelude_pins_cpu(monkeypatch):
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert "jax_platforms" in supervisor.default_prelude()
-    monkeypatch.setenv("JAX_PLATFORMS", "")
-    assert supervisor.default_prelude() == ""
+@pytest.mark.parametrize("platforms,chips,n,want", [
+    ("cpu", 4, 8, [{}] * 8),      # CPU-pinned children: unaffected
+    ("", 0, 3, [{}] * 3),         # no TPU chip on this host
+    ("", 4, 2, ["0", "1"]),       # one visible chip per child
+    ("", 2, 3, None),             # more children than chips: refuse
+])
+def test_one_chip_envs(monkeypatch, platforms, chips, n, want):
+    monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: chips)
+    got = supervisor.one_chip_envs(n, {"JAX_PLATFORMS": platforms})
+    if want and isinstance(want[0], str):
+        assert [e["TPU_VISIBLE_CHIPS"] for e in got] == want
+        assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+                   for e in got)
+        assert len({e["TPU_PROCESS_PORT"] for e in got}) == n
+    else:
+        assert got == want
+
+
+@pytest.mark.parametrize("launcher", ["shepherd", "serve_fleet"])
+def test_launcher_refuses_more_chip_children_than_chips(
+        monkeypatch, tmp_path, capsys, launcher):
+    """Two processes cannot share a chip: a local launcher asked for
+    more chip-using children than the host has chips exits rc 1 before
+    spawning anything."""
+    monkeypatch.setattr(supervisor, "local_tpu_chips", lambda: 1)
+    monkeypatch.setattr(supervisor.subprocess, "Popen", None)  # no spawn
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    if launcher == "shepherd":
+        rc = supervisor.shepherd_run(
+            "in.fa", str(tmp_path / "o.fa"), 2, ["in.fa", "o.fa"],
+            env=env)
+    else:
+        rc = supervisor.serve_fleet_run(str(tmp_path / "spool"), 2, [],
+                                        env=env)
+    assert rc == exitcodes.RC_FATAL
+    assert "TPU chips" in capsys.readouterr().err
 
 
 def test_latest_mtime(tmp_path):
