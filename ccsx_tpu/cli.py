@@ -42,10 +42,13 @@ TPU extensions (long options):
 --refine-iters <int>      --max-passes <int>      --window-growth {flush,grow}
 --journal <path>          --metrics <path>        --profile <dir>
 --trace <path>            (dispatch flight recorder: span JSONL +
-                           Chrome/Perfetto trace export; device spans
-                           close only after block_until_ready, and the
-                           per-shape-group compile/execute table rides
-                           every --metrics event)
+                           Chrome/Perfetto trace export; never blocks a
+                           dispatch.  The per-shape-group compile and
+                           dispatch counts ride every --metrics event.
+                           Device time per program and per stage comes
+                           from a profiler trace (--profile), which
+                           names the ccsx.* spans, ccsx_* programs and
+                           the fill/traceback/vote/breakpoint scopes)
 --stall-timeout <sec>     (hang watchdog: a device dispatch open this
                            long dumps all thread stacks + the in-flight
                            shape group and marks the run degraded;
@@ -181,7 +184,7 @@ ccsx-tpu top <src>...     (live ANSI dashboard over telemetry
                            --once for one frame)
 ccsx-tpu report <jsonl>.. (self-contained HTML run report from trace/
                            metrics JSONL: timeline strip, group
-                           compile/execute table, stage breakdown,
+                           compile/dispatch table, stage breakdown,
                            occupancy tiles, stall/recovery log,
                            ETA-vs-actual curve; -o <out.html>.
                            With --fleet <dir>: stitch a fleet/spool
@@ -237,8 +240,8 @@ ccsx-tpu lint [files...]  (repo-native static analysis, pure ast — no
                            code, bare writes in lease/journal/spool
                            domains, off-lock Metrics mutation,
                            ContextVar set without token restore,
-                           device spans closing unforced, and the
-                           static telemetry schema cross-check.
+                           and the static telemetry schema
+                           cross-check.
                            Suppressions live in lint_baseline.json
                            (committed, every entry justified) or
                            inline `# lint: ok[check] reason`; --json
@@ -379,10 +382,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--trace", default=None,
                    help="Dispatch flight recorder: write span JSONL "
                         "here (+ a Chrome trace-event export at close; "
-                        "utils/trace.py).  Device spans use the "
-                        "forced-execution close, and the per-group "
-                        "compile/execute table rides every metrics "
-                        "event")
+                        "utils/trace.py).  Never blocks a dispatch; the "
+                        "per-group compile and dispatch counts ride "
+                        "every metrics event.  Device time per program "
+                        "and per stage comes from a profiler trace "
+                        "(--profile), where the ccsx.* spans, the "
+                        "ccsx_* programs "
+                        "and the fill/traceback/vote/breakpoint scopes "
+                        "are named")
     p.add_argument("--stall-timeout", type=float, default=120.0,
                    dest="stall_timeout", metavar="SEC",
                    help="Hang watchdog: dump thread stacks + the "

@@ -199,8 +199,8 @@ class CcsConfig:
     metrics_path: Optional[str] = None  # JSON-lines metrics events
     trace_path: Optional[str] = None    # CLI --trace: dispatch flight
     #   recorder (utils/trace.py) — span JSONL + Chrome trace export,
-    #   forced-execution device spans, per-shape-group compile/execute
-    #   attribution merged into every metrics event
+    #   per-shape-group compile/dispatch counts merged into every
+    #   metrics event
     stall_timeout_s: float = 120.0      # CLI --stall-timeout: the hang
     #   watchdog fires when a device-dispatch span stays open this long,
     #   dumping thread stacks + the in-flight shape group (0 disables)

@@ -17,8 +17,6 @@ shipped and hand-reviewed out, one checker per family:
                        ``bump()``/``add_stage()``
 - ``contextvar-restore`` ``ContextVar.set()`` with no token restore in
                        a ``finally`` (the r17 cid cross-stamp shape)
-- ``span-force``       ``device_span`` blocks that close without
-                       forcing execution (lazy-runtime timing lies)
 - ``schema-drift``     the static complement of the runtime telemetry
                        schema guard: consumed keys exist in
                        ``Metrics.snapshot()`` and snapshot keys reach

@@ -97,7 +97,7 @@ def _register() -> None:
         return
     from ccsx_tpu.lint import (
         checks_concurrency, checks_crashsafe, checks_numeric,
-        checks_schema, checks_spans,
+        checks_schema,
     )
 
     FILE_CHECKS.extend([
@@ -105,7 +105,6 @@ def _register() -> None:
         (checks_crashsafe.CHECK, checks_crashsafe.check),
         (checks_concurrency.CHECK_LOCK, checks_concurrency.check_metrics_lock),
         (checks_concurrency.CHECK_CVAR, checks_concurrency.check_contextvar),
-        (checks_spans.CHECK, checks_spans.check),
     ])
     TREE_CHECKS.append((checks_schema.CHECK, checks_schema.check_tree))
 

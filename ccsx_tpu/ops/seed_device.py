@@ -52,7 +52,7 @@ def seed_step(qmax: int, tmax: int):
     # median sentinel: larger than any real diagonal of these shapes
     big = jnp.int32(qmax + tmax + 2 * sketch_mod.DIAG_BIN)
 
-    def one(row, lens):
+    def ccsx_seed(row, lens):
         q = row[:qmax]
         t = row[qmax:]
         qlen, tlen = lens[0], lens[1]
@@ -89,7 +89,8 @@ def seed_step(qmax: int, tmax: int):
                          total])
         return out.astype(jnp.int32)
 
-    return jax.jit(jax.vmap(one))
+    # jit names the program after the function vmap wraps
+    return jax.jit(jax.vmap(ccsx_seed))
 
 
 def hit_from_row(row) -> Optional[seed_mod.SeedHit]:

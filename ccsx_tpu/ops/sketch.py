@@ -323,7 +323,7 @@ def screen_step(qmax: int, tmax: int):
 
     nb = (qmax + tmax) // DIAG_BIN + 2
 
-    def one(row, lens):
+    def ccsx_prefilter(row, lens):
         q = row[:qmax]
         t = row[qmax:]
         qlen, tlen = lens[0], lens[1]
@@ -340,4 +340,5 @@ def screen_step(qmax: int, tmax: int):
                           jnp.where(empty, 0, votes),
                           jnp.where(empty, 0, win_lo)])
 
-    return jax.jit(jax.vmap(one))
+    # jit names the program after the function vmap wraps
+    return jax.jit(jax.vmap(ccsx_prefilter))

@@ -63,6 +63,9 @@ from ccsx_tpu.utils.journal import Journal
 from ccsx_tpu.utils.metrics import (FailureBudgetExceeded, Metrics,
                                     check_failure_budget)
 
+# the named scopes of a round's stages, which a profiler trace reads
+_FILL, _TRACEBACK, _VOTE, _BREAKPOINT = trace.STAGES
+
 
 # ---- failure taxonomy (the fault-tolerance layer's classification of
 # ---- exceptions escaping a jitted device dispatch; ARCHITECTURE.md
@@ -144,6 +147,16 @@ def _out_shape_tag(out):
         return None
 
 
+def _fetch_finish(idxs, key, out, finish) -> None:
+    """The host side of a materialized dispatch: the d2h copy of its
+    outputs ("fetch"), then ``finish`` scattering them into results
+    ("finish"), each a span of its own."""
+    with trace.span("fetch", cat="compute"):
+        out = jax.device_get(out)
+    with trace.span("finish", cat="compute"):
+        finish(idxs, key, out)
+
+
 def _bounded(resil, label_str, phase, fn):
     """Deadline-bound ``fn`` through the run's Resilience object (a
     plain call when deadlines are off / no resilience is wired)."""
@@ -188,7 +201,7 @@ def _run_group_sync(idxs, key, dispatch, finish, host_one, results,
                                attribute=False, n=len(idxs)):
             out = _bounded(resil, label(key), "materialize",
                            lambda: jax.block_until_ready(out))
-        finish(idxs, key, out)
+        _fetch_finish(idxs, key, out, finish)
         if probe and resil is not None:
             resil.breaker.probe_succeeded()
     except Exception as e:
@@ -262,7 +275,8 @@ def _run_groups_recovering(groups, dispatch, finish, host_one, results,
     """Happy path: dispatch every group's device work before
     materializing any result (jit dispatch is async, so group B's
     compute overlaps group A's d2h transfer); failures at either
-    phase drop that one group into the recovery ladder.  ``label``
+    phase drop that one group into the recovery ladder.  ``finish``
+    receives the outputs as host arrays (_fetch_finish).  ``label``
     maps a group key to the STABLE trace-group string the dispatch
     spans use (e.g. dropping the packed path's per-slab ordinal), so
     materialize spans share the dispatch namespace and the watchdog's
@@ -314,7 +328,7 @@ def _run_groups_recovering(groups, dispatch, finish, host_one, results,
                                    attribute=False, n=len(idxs)):
                 out = _bounded(resil, label(key), "materialize",
                                lambda o=out: jax.block_until_ready(o))
-            finish(idxs, key, out)
+            _fetch_finish(idxs, key, out, finish)
             # only THE probe's own completion settles the breaker — a
             # concurrent pre-trip group finishing must not close it on
             # stale evidence (the admit() token carries the identity)
@@ -344,17 +358,20 @@ def _round_body(params: AlignParams, max_ins: int, tmax: int):
         Z, P, qmax = qs.shape
         ts_b = jax.numpy.broadcast_to(draft[:, None, :], (Z, P, tmax))
         tl_b = jax.numpy.broadcast_to(dlen[:, None], (Z, P))
-        _, moves, offs = aligner(
-            qs.reshape(Z * P, qmax), qlens.reshape(Z * P),
-            ts_b.reshape(Z * P, tmax), tl_b.reshape(Z * P))
+        with jax.named_scope(_FILL):
+            _, moves, offs = aligner(
+                qs.reshape(Z * P, qmax), qlens.reshape(Z * P),
+                ts_b.reshape(Z * P, tmax), tl_b.reshape(Z * P))
         moves = moves.reshape(Z, P, qmax, -1)
         offs = offs.reshape(Z, P, qmax)
         proj = jax.vmap(jax.vmap(projector, in_axes=(0, 0, 0, 0, None)),
                         in_axes=(0, 0, 0, 0, 0))
-        aligned, ins_cnt, ins_b, lead_ins = proj(
-            moves, offs, qs, qlens, dlen)
-        cons, ins_base, ins_votes, ncov, match, nwin = jax.vmap(voter)(
-            aligned, ins_cnt, ins_b, row_mask)
+        with jax.named_scope(_TRACEBACK):
+            aligned, ins_cnt, ins_b, lead_ins = proj(
+                moves, offs, qs, qlens, dlen)
+        with jax.named_scope(_VOTE):
+            cons, ins_base, ins_votes, ncov, match, nwin = jax.vmap(
+                voter)(aligned, ins_cnt, ins_b, row_mask)
         return (cons, ins_base, ins_votes, ncov, nwin, match, aligned,
                 ins_cnt, lead_ins)
 
@@ -391,8 +408,9 @@ def _round_step(params: AlignParams, max_ins: int, tmax: int,
     def core(qs, qlens, ts, tlens, row_mask):
         (cons, ins_base, ins_votes, ncov, nwin, match, aligned, ins_cnt,
          lead_ins) = body(qs, qlens, row_mask, ts, tlens)
-        bp, advance = jax.vmap(bp_advance)(
-            match, cons, aligned, ins_cnt, lead_ins, row_mask, tlens)
+        with jax.named_scope(_BREAKPOINT):
+            bp, advance = jax.vmap(bp_advance)(
+                match, cons, aligned, ins_cnt, lead_ins, row_mask, tlens)
         # compact the d2h payload: votes/coverage are bounded by the pass
         # count (<= 64 with the largest pass bucket), so uint8 halves the
         # transfer; the host casts back before arithmetic
@@ -402,11 +420,14 @@ def _round_step(params: AlignParams, max_ins: int, tmax: int,
                 nwin.astype(jnp.uint8), bp, advance)
 
     if pack is None:
+        # jit names the program after the function it traces, which
+        # names the dispatch site in a profiler trace's "XLA Modules"
+        core.__name__ = "ccsx_round"
         return jax.jit(core)
     P, qmax = pack
 
     @jax.jit
-    def step(big, small):
+    def ccsx_round(big, small):
         qs, qlens, ts, tlens, row_mask = _unpack_args_jax(
             big, small, P, qmax, tmax)
         cons, ins_base, ins_votes, ncov, nwin, bp, advance = core(
@@ -421,7 +442,7 @@ def _round_step(params: AlignParams, max_ins: int, tmax: int,
             [bp[:, None], advance], axis=1).astype(jnp.int32)
         return big_out, small_out
 
-    return step
+    return ccsx_round
 
 
 def _pack_args(args):
@@ -531,8 +552,9 @@ def _refine_step(params: AlignParams, max_ins: int, tmax: int, iters: int,
                 jnp.where(fixed.reshape((Z,) + (1,) * (n.ndim - 1)), o, n)
                 for o, n in zip(outs, new))
             cons, ins_base, ins_votes, ncov = outs[:4]
-            ins_out = spec_emit(ins_base, ins_votes, ncov)
-            nd, nl, o = mat_v(cons, ins_out, dlen)
+            with jax.named_scope(_VOTE):
+                ins_out = spec_emit(ins_base, ins_votes, ncov)
+                nd, nl, o = mat_v(cons, ins_out, dlen)
             # fixpoint: same length AND same padded cells == the host's
             # np.array_equal on the exact-length drafts (pads are PAD on
             # both sides, and a length change forces a cell change)
@@ -590,19 +612,21 @@ def _refine_step(params: AlignParams, max_ins: int, tmax: int, iters: int,
             cond, body, (jnp.int32(0), ts, tlens, fixed0, ovf0, outs0))
         (cons, ins_base, ins_votes, ncov, nwin, match, aligned, ins_cnt,
          lead_ins) = outs
-        bp, advance = jax.vmap(bp_advance)(
-            match, cons, aligned, ins_cnt, lead_ins, row_mask, dlen)
+        with jax.named_scope(_BREAKPOINT):
+            bp, advance = jax.vmap(bp_advance)(
+                match, cons, aligned, ins_cnt, lead_ins, row_mask, dlen)
         # uint8 vote/coverage compaction, as in _round_step
         return (cons, ins_base, ins_votes.astype(jnp.uint8),
                 ncov.astype(jnp.uint8), nwin.astype(jnp.uint8),
                 bp, advance, dlen, ovf)
 
     if pack is None:
+        core.__name__ = "ccsx_refine"    # the program's name, as above
         return jax.jit(core)
     P, qmax = pack
 
     @jax.jit
-    def step(big, small):
+    def ccsx_refine(big, small):
         args = _unpack_args_jax(big, small, P, qmax, tmax)
         (cons, ins_base, ins_votes, ncov, nwin, bp, advance, dlen,
          ovf) = core(*args)
@@ -617,7 +641,7 @@ def _refine_step(params: AlignParams, max_ins: int, tmax: int, iters: int,
              ovf[:, None].astype(jnp.int32)], axis=1).astype(jnp.int32)
         return big_out, small_out
 
-    return step
+    return ccsx_refine
 
 
 def _unpack_refine(big, small, max_ins: int, tmax: int):
@@ -652,12 +676,15 @@ def _round_body_packed(params: AlignParams, max_ins: int, tmax: int,
     def body(qs, qlens, row_mask, seg, draft, dlen):
         ts_r = draft[seg]          # (R, tmax) per-row targets
         tl_r = dlen[seg]           # (R,)
-        _, moves, offs = aligner(qs, qlens, ts_r, tl_r)
+        with jax.named_scope(_FILL):
+            _, moves, offs = aligner(qs, qlens, ts_r, tl_r)
         proj = jax.vmap(projector, in_axes=(0, 0, 0, 0, 0))
-        aligned, ins_cnt, ins_b, lead_ins = proj(
-            moves, offs, qs, qlens, tl_r)
-        cons, ins_base, ins_votes, ncov, match, nwin = voter(
-            aligned, ins_cnt, ins_b, row_mask, seg)
+        with jax.named_scope(_TRACEBACK):
+            aligned, ins_cnt, ins_b, lead_ins = proj(
+                moves, offs, qs, qlens, tl_r)
+        with jax.named_scope(_VOTE):
+            cons, ins_base, ins_votes, ncov, match, nwin = voter(
+                aligned, ins_cnt, ins_b, row_mask, seg)
         return (cons, ins_base, ins_votes, ncov, nwin, match, aligned,
                 ins_cnt, lead_ins)
 
@@ -712,8 +739,9 @@ def _refine_core_packed(params: AlignParams, max_ins: int, tmax: int,
                 for o, n in zip(outs[5:], new[5:])
             )
             cons, ins_base, ins_votes, ncov = outs[:4]
-            ins_out = spec_emit(ins_base, ins_votes, ncov)
-            nd, nl, o = mat_v(cons, ins_out, dlen)
+            with jax.named_scope(_VOTE):
+                ins_out = spec_emit(ins_base, ins_votes, ncov)
+                nd, nl, o = mat_v(cons, ins_out, dlen)
             now_fixed = (nl == dlen) & (nd == draft).all(axis=1)
             last = it >= iters
             o = ~fixed & o & ~last
@@ -749,8 +777,9 @@ def _refine_core_packed(params: AlignParams, max_ins: int, tmax: int,
             cond, body, (jnp.int32(0), ts, tlens, fixed0, ovf0, outs0))
         (cons, ins_base, ins_votes, ncov, nwin, match, aligned, ins_cnt,
          lead_ins) = outs
-        bp, advance = bp_advance(match, cons, aligned, ins_cnt, lead_ins,
-                                 row_mask, seg, dlen)
+        with jax.named_scope(_BREAKPOINT):
+            bp, advance = bp_advance(match, cons, aligned, ins_cnt,
+                                     lead_ins, row_mask, seg, dlen)
         # uint8 vote/coverage compaction, as in _round_step (bounded by
         # the hole's real row count <= max_passes)
         return (cons, ins_base, ins_votes.astype(jnp.uint8),
@@ -796,7 +825,7 @@ def _packed_wire_step(params: AlignParams, max_ins: int, tmax: int,
     H = nseg
     Lbig, Lsmall = _slab_wire_sizes(R, qmax, H, tmax, max_ins)
 
-    def step(big, small):
+    def ccsx_refine_packed(big, small):
         args = _unpack_slab_args_jax(big, small, R, qmax, H, tmax)
         (cons, ins_base, ins_votes, ncov, nwin, bp, advance, dlen,
          ovf) = core(*args)
@@ -809,7 +838,7 @@ def _packed_wire_step(params: AlignParams, max_ins: int, tmax: int,
         small_out = jnp.pad(small_out, (0, Lsmall - small_out.shape[0]))
         return big_out, small_out
 
-    return step
+    return ccsx_refine_packed
 
 
 @functools.lru_cache(maxsize=128)
@@ -852,8 +881,12 @@ def _refine_step_packed_fused(params: AlignParams, max_ins: int,
     R, qmax = pack
     step = _packed_wire_step(params, max_ins, tmax, iters, nseg,
                              bp_consts, R, qmax)
+
+    def ccsx_refine_packed_fused(bigs, smalls):
+        return jax.vmap(step)(bigs, smalls)
+
     sh = shard_map_nocheck(
-        lambda bigs, smalls: jax.vmap(step)(bigs, smalls), mesh,
+        ccsx_refine_packed_fused, mesh,
         in_specs=(PS("slab", None), PS("slab", None)),
         out_specs=(PS("slab", None), PS("slab", None)))
     return jax.jit(sh, donate_argnums=(0, 1))
@@ -933,7 +966,7 @@ def _pair_fill_packed(params: AlignParams, qmax: int, tmax: int):
     fill = _pair_fill(params)
 
     @jax.jit
-    def step(big, small):
+    def ccsx_pair_fill(big, small):
         qs = big[:, :qmax]
         ts = big[:, qmax:qmax + tmax]
         qlens, tlens, ls = small[:, 0], small[:, 1], small[:, 2:6]
@@ -942,7 +975,7 @@ def _pair_fill_packed(params: AlignParams, qmax: int, tmax: int):
             [r.score, r.qb, r.qe, r.tb, r.te, r.aln, r.mat],
             axis=1).astype(jnp.int32)
 
-    return step
+    return ccsx_pair_fill
 
 
 class PairExecutor:
@@ -1197,13 +1230,12 @@ class PairExecutor:
             step = sketch_mod.screen_step(qmax, tmax)
             with trace.device_span(
                     "sketch_screen", group=f"sketch:q{qmax}:t{tmax}",
-                    shape=f"N{N}", n=len(gidxs)) as sp:
+                    shape=f"N{N}", n=len(gidxs)):
                 faultinject.fire("stall")
                 faultinject.fire("device_hang")
-                return sp.force(step(big, small))
+                return step(big, small)
 
         def finish(gidxs, key, out):
-            out = np.asarray(out)
             for z, i in enumerate(gidxs):
                 triples[i] = tuple(int(v) for v in out[z])
 
@@ -1255,13 +1287,12 @@ class PairExecutor:
             step = sd_mod.seed_step(qmax, tmax)
             with trace.device_span(
                     "seed_device", group=f"seed:q{qmax}:t{tmax}",
-                    shape=f"N{N}", n=len(gidxs)) as sp:
+                    shape=f"N{N}", n=len(gidxs)):
                 faultinject.fire("stall")
                 faultinject.fire("device_hang")
-                return sp.force(step(big, small))
+                return step(big, small)
 
         def finish(gidxs, key, out):
-            out = np.asarray(out)
             for z, i in enumerate(gidxs):
                 rows[i] = [int(v) for v in out[z]]
 
@@ -1417,13 +1448,12 @@ class PairExecutor:
             with trace.device_span(
                     "pair_fill", group=f"pair:q{qmax}:t{tmax}",
                     cells=N * qmax * self.params.band,
-                    shape=f"N{N}", n=len(idxs)) as sp:
+                    shape=f"N{N}", n=len(idxs)):
                 faultinject.fire("stall")
                 faultinject.fire("device_hang")
-                return sp.force(step(big, small))
+                return step(big, small)
 
         def finish(idxs, key, res):
-            res = np.asarray(res)
             for z, i in enumerate(idxs):
                 score, qb, qe, tb, te, aln, mat = (
                     int(v) for v in res[z])
@@ -1819,10 +1849,10 @@ class BatchExecutor:
         so the while_loop exits at iteration 0 and the execution costs
         ~a breakpoint scan; what it buys is the exact jit fast path
         primed (fn.lower().compile() shares the XLA compile but leaves
-        a retrace + dispatch-cache miss on the first real call, which
-        would then book as execute time).  The warmup=True span books
-        the (group, shape)'s compile, so the first real dispatch books
-        as execute — the trace-visible proof the overlap worked."""
+        a retrace + dispatch-cache miss on the first real call).  The
+        warmup=True span books the (group, shape)'s compile, so the
+        first real dispatch books none — the trace-visible proof the
+        overlap worked."""
         cfg = self.cfg
         Lbig, Lsmall = _slab_wire_sizes(R, qmax, H, tmax,
                                         cfg.max_ins_per_col)
@@ -1964,7 +1994,7 @@ class BatchExecutor:
             with trace.device_span(
                     "round", group=f"round:P{P}:q{qmax}:t{tmax}:b{bimpl}",
                     cells=Z * P * qmax * cfg.align.band,
-                    shape=f"Z{Z}", n=len(idxs), Z=Z) as sp:
+                    shape=f"Z{Z}", n=len(idxs), Z=Z):
                 faultinject.fire("stall")
                 faultinject.fire("device_hang")
                 if self._mesh is None:
@@ -1972,14 +2002,13 @@ class BatchExecutor:
                     step = _round_step(cfg.align, cfg.max_ins_per_col,
                                        tmax, self._bp_consts(),
                                        pack=(P, qmax))
-                    return sp.force(step(*_pack_args(args)))
+                    return step(*_pack_args(args))
                 step = _round_step(cfg.align, cfg.max_ins_per_col, tmax,
                                    self._bp_consts())
-                return sp.force(step(*self._shard_args(args, P)))
+                return step(*self._shard_args(args, P))
 
         def finish(idxs, key, out):
             P, qmax, tmax = key
-            out = tuple(np.asarray(o) for o in out)
             if self._mesh is None:
                 (cons, ins_base, ins_votes, ncov, nwin, bp,
                  advance) = _unpack_round(
@@ -2040,7 +2069,7 @@ class BatchExecutor:
                     "refine",
                     group=f"refine:P{P}:q{qmax}:t{tmax}:i{iters}:b{bimpl}",
                     cells=Z * P * qmax * cfg.align.band * iters,
-                    shape=f"Z{Z}", n=len(idxs), Z=Z) as sp:
+                    shape=f"Z{Z}", n=len(idxs), Z=Z):
                 faultinject.fire("stall")
                 faultinject.fire("device_hang")
                 if self._mesh is None:
@@ -2049,14 +2078,13 @@ class BatchExecutor:
                     step = _refine_step(cfg.align, cfg.max_ins_per_col,
                                         tmax, iters, self._bp_consts(),
                                         pack=(P, qmax))
-                    return sp.force(step(*_pack_args(args)))
+                    return step(*_pack_args(args))
                 step = _refine_step(cfg.align, cfg.max_ins_per_col, tmax,
                                     iters, self._bp_consts())
-                return sp.force(step(*self._shard_args(args, P)))
+                return step(*self._shard_args(args, P))
 
         def finish(idxs, key, out):
             P, qmax, tmax, iters = key
-            out = tuple(np.asarray(o) for o in out)
             if self._mesh is None:
                 (cons, ins_base, ins_votes, ncov, nwin, bp, advance,
                  dlen, ovf) = _unpack_refine(
@@ -2200,10 +2228,12 @@ class BatchExecutor:
             if self.metrics is not None:
                 self.metrics.bump_banded(bimpl)
             if not fused:
-                args = self._stack_slab(requests, idxs, qmax, tmax)
+                with trace.span("pack", cat="compute"):
+                    args = self._stack_slab(requests, idxs, qmax, tmax)
+                    big, small = _pack_slab_args(args,
+                                                 cfg.max_ins_per_col)
                 R = args[0].shape[0]
                 H = args[4].shape[0]
-                big, small = _pack_slab_args(args, cfg.max_ins_per_col)
                 self._warm_wait(self._warm_key(qmax, tmax, iters, R, 1))
                 self._note_shape(R, qmax, tmax, iters)
                 step = _refine_step_packed(
@@ -2215,10 +2245,10 @@ class BatchExecutor:
                         cells=R * qmax * band * iters,
                         shape=f"R{R}:S{H}",
                         plan={"slab": key[3], "rows": R,
-                              "holes": len(idxs)}) as sp:
+                              "holes": len(idxs)}):
                     faultinject.fire("stall")
                     faultinject.fire("device_hang")
-                    return sp.force(step(big, small))
+                    return step(big, small)
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as PS
 
@@ -2228,13 +2258,15 @@ class BatchExecutor:
             K = -(-len(plan) // D)
             Lbig, Lsmall = _slab_wire_sizes(R, qmax, H, tmax,
                                             cfg.max_ins_per_col)
-            bigs = np.zeros((K * D, Lbig), np.uint8)
-            smalls = np.zeros((K * D, Lsmall), np.int32)
-            for d, s in enumerate(plan):
-                args = self._stack_slab(requests, [idxs[j] for j in s],
-                                        qmax, tmax, shape=(R, H))
-                bigs[d], smalls[d] = _pack_slab_args(
-                    args, cfg.max_ins_per_col)
+            with trace.span("pack", cat="compute"):
+                bigs = np.zeros((K * D, Lbig), np.uint8)
+                smalls = np.zeros((K * D, Lsmall), np.int32)
+                for d, s in enumerate(plan):
+                    args = self._stack_slab(requests,
+                                            [idxs[j] for j in s],
+                                            qmax, tmax, shape=(R, H))
+                    bigs[d], smalls[d] = _pack_slab_args(
+                        args, cfg.max_ins_per_col)
             # dummy tail slabs stay all-zero: an empty row mask freezes
             # every segment, so that chip exits the while_loop at
             # iteration 0
@@ -2251,12 +2283,12 @@ class BatchExecutor:
                     shape=f"D{K * D}:R{R}:S{H}",
                     plan={"wave": key[3], "slabs": len(plan),
                           "chips": D, "rows": R,
-                          "holes": len(idxs)}) as sp:
+                          "holes": len(idxs)}):
                 faultinject.fire("stall")
                 faultinject.fire("device_hang")
                 big = jax.device_put(bigs, sharding)
                 small = jax.device_put(smalls, sharding)
-                return sp.force(step(big, small))
+                return step(big, small)
 
         def _finish_slab(sl_idxs, tmax, big, small, R, H):
             (cons, ins_base, ins_votes, ncov, nwin, bp, advance, dlen,
@@ -2289,7 +2321,7 @@ class BatchExecutor:
 
         def finish(idxs, key, out):
             qmax, tmax, iters, _ = key
-            big, small = np.asarray(out[0]), np.asarray(out[1])
+            big, small = out
             if not fused:
                 R, H = pack_mod.slab_shape(
                     [nrows[i] for i in idxs], self.slab_rows,
@@ -2496,49 +2528,53 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
 
     def emit_ready():
         nonlocal next_emit
-        while next_emit in finished:
-            h = finished.pop(next_emit)
-            if h.resumed:
+        if next_emit not in finished:
+            return
+        # one span per run of holes retired: write + journal
+        with trace.span("emit", cat="write"):
+            while next_emit in finished:
+                h = finished.pop(next_emit)
+                if h.resumed:
+                    next_emit += 1
+                    if pool is not None:
+                        pool.release()
+                    continue
+                wrote = False
+                if h.err is not None:
+                    metrics.holes_failed += 1
+                    print(f"[ccsx-tpu] hole {h.zmw.movie}/{h.zmw.hole} "
+                          f"failed: {h.err}", file=sys.stderr)
+                    # failure-rate abort (--max-failed-holes): quarantine
+                    # is no longer unbounded — a count budget aborts here,
+                    # a fraction budget at end of run (metrics.py)
+                    check_failure_budget(metrics, cfg)
+                elif h.cns is not None and h.cns[0]:
+                    name = f"{h.zmw.movie}/{h.zmw.hole}/ccs"
+                    seq, qual = h.cns
+                    with metrics.timer("write"), \
+                            trace.span("write_record", cat="write"):
+                        if put_at is not None:
+                            put_at(h.idx, name, seq, qual)
+                        else:
+                            writer.put(name, seq, qual)
+                    metrics.holes_out += 1
+                    wrote = True
+                # flush-before-cursor + write fault point + advance: the
+                # shared crash invariant lives in Journal.retire
+                journal.retire(writer, wrote, metrics)
+                # rank_death models a sharded rank SIGKILLed mid-run (the
+                # shepherd's restart-and-resume acceptance case): fired at
+                # a retirement point so the dead rank leaves a valid
+                # journal + durable records behind, exactly like a real
+                # OOM-kill between holes
+                faultinject.fire("rank_death")
+                # sigterm delivers a REAL signal at the same point — the
+                # graceful-drain path, made deterministic
+                faultinject.fire("sigterm")
+                metrics.tick()
                 next_emit += 1
                 if pool is not None:
-                    pool.release()
-                continue
-            wrote = False
-            if h.err is not None:
-                metrics.holes_failed += 1
-                print(f"[ccsx-tpu] hole {h.zmw.movie}/{h.zmw.hole} "
-                      f"failed: {h.err}", file=sys.stderr)
-                # failure-rate abort (--max-failed-holes): quarantine
-                # is no longer unbounded — a count budget aborts here,
-                # a fraction budget at end of run (metrics.py)
-                check_failure_budget(metrics, cfg)
-            elif h.cns is not None and h.cns[0]:
-                name = f"{h.zmw.movie}/{h.zmw.hole}/ccs"
-                seq, qual = h.cns
-                with metrics.timer("write"), \
-                        trace.span("write_record", cat="write"):
-                    if put_at is not None:
-                        put_at(h.idx, name, seq, qual)
-                    else:
-                        writer.put(name, seq, qual)
-                metrics.holes_out += 1
-                wrote = True
-            # flush-before-cursor + write fault point + advance: the
-            # shared crash invariant lives in Journal.retire
-            journal.retire(writer, wrote, metrics)
-            # rank_death models a sharded rank SIGKILLed mid-run (the
-            # shepherd's restart-and-resume acceptance case): fired at
-            # a retirement point so the dead rank leaves a valid
-            # journal + durable records behind, exactly like a real
-            # OOM-kill between holes
-            faultinject.fire("rank_death")
-            # sigterm delivers a REAL signal at the same point — the
-            # graceful-drain path, made deterministic
-            faultinject.fire("sigterm")
-            metrics.tick()
-            next_emit += 1
-            if pool is not None:
-                pool.release()  # free one slot of ingest-ahead budget
+                    pool.release()  # free one slot of ingest-ahead budget
 
     def admit(h):
         if h.done:
@@ -2600,58 +2636,60 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                             resume=resume)
         while True:
             admitted_full = False
-            if pool is not None:
-                # drain whatever prep has finished, up to the window —
-                # NEVER blocking here: with device work pending, the
-                # sweep must run while prep keeps working in background
-                while len(active) < window:
-                    if adm is not None and not adm.try_acquire():
-                        break  # at fair share; sweep what we hold
-                    h = pool.poll()
-                    if h is None:
-                        if adm is not None:
-                            adm.release()  # nothing arrived for it
-                        break
-                    admit(h)
-                admitted_full = len(active) >= window
-            else:
-                # inline prep (--prep-threads 0): admit up to the
-                # window; bound TOTAL outstanding holes (incl.
-                # instantly-finished ones parked for ordered emission)
-                # so a filtered run can't grow memory unboundedly
-                while (not exhausted and len(active) < window
-                       and next_idx - next_emit < 4 * cap):
-                    if adm is not None and not adm.try_acquire():
-                        break  # at fair share; sweep what we hold
-                    try:
-                        with metrics.timer("ingest"), \
-                                trace.span("ingest_hole", cat="ingest"):
-                            z = next(stream)
-                            faultinject.fire("ingest")
-                    except StopIteration:
-                        if adm is not None:
-                            adm.release()
-                        exhausted = True
-                        break
-                    metrics.holes_in += 1
-                    h = _Hole(idx=next_idx, zmw=z)
-                    next_idx += 1
-                    if metrics.holes_in <= resume:
-                        h.done = h.resumed = True
-                    else:
-                        # prep host work (grouping + first generator
-                        # step) timed as its own stage AND as driver-
-                        # blocked prep (inline prep is all critical
-                        # path); the walk's pair alignments are batched
-                        # below (benchmarks/prep_share.py is the
-                        # criterion that forced this)
-                        with metrics.timer("prep"), \
-                                metrics.timer("prep_blocked"), \
-                                trace.span("prep_hole", cat="prep",
-                                           hole=str(z.hole)):
-                            _start_hole(h, cfg)
-                    admit(h)
-                admitted_full = len(active) >= window
+            # the pool poll (or inline ingest + prep) and admission
+            with trace.span("admit", cat="host"):
+                if pool is not None:
+                    # drain whatever prep has finished, up to the window —
+                    # NEVER blocking here: with device work pending, the
+                    # sweep must run while prep keeps working in background
+                    while len(active) < window:
+                        if adm is not None and not adm.try_acquire():
+                            break  # at fair share; sweep what we hold
+                        h = pool.poll()
+                        if h is None:
+                            if adm is not None:
+                                adm.release()  # nothing arrived for it
+                            break
+                        admit(h)
+                    admitted_full = len(active) >= window
+                else:
+                    # inline prep (--prep-threads 0): admit up to the
+                    # window; bound TOTAL outstanding holes (incl.
+                    # instantly-finished ones parked for ordered emission)
+                    # so a filtered run can't grow memory unboundedly
+                    while (not exhausted and len(active) < window
+                           and next_idx - next_emit < 4 * cap):
+                        if adm is not None and not adm.try_acquire():
+                            break  # at fair share; sweep what we hold
+                        try:
+                            with metrics.timer("ingest"), \
+                                    trace.span("ingest_hole", cat="ingest"):
+                                z = next(stream)
+                                faultinject.fire("ingest")
+                        except StopIteration:
+                            if adm is not None:
+                                adm.release()
+                            exhausted = True
+                            break
+                        metrics.holes_in += 1
+                        h = _Hole(idx=next_idx, zmw=z)
+                        next_idx += 1
+                        if metrics.holes_in <= resume:
+                            h.done = h.resumed = True
+                        else:
+                            # prep host work (grouping + first generator
+                            # step) timed as its own stage AND as driver-
+                            # blocked prep (inline prep is all critical
+                            # path); the walk's pair alignments are batched
+                            # below (benchmarks/prep_share.py is the
+                            # criterion that forced this)
+                            with metrics.timer("prep"), \
+                                    metrics.timer("prep_blocked"), \
+                                    trace.span("prep_hole", cat="prep",
+                                               hole=str(z.hole)):
+                                _start_hole(h, cfg)
+                        admit(h)
+                    admitted_full = len(active) >= window
             emit_ready()
             if not active:
                 if pool is None:
@@ -2667,35 +2705,36 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                 # sweep into near-empty slabs and per-hole dispatches);
                 # the moment prep pauses with work in hand — or the
                 # window fills — sweep what we have.
-                while len(active) < window and not pool.drained():
-                    if adm is not None and not adm.try_acquire():
-                        # at fair share while another tenant wants the
-                        # window: wait on the admission condition (a
-                        # release anywhere re-checks), not on the pool
-                        adm.wait(0.05 if active else 0.2)
+                with trace.span("admit", cat="host"):
+                    while len(active) < window and not pool.drained():
+                        if adm is not None and not adm.try_acquire():
+                            # at fair share while another tenant wants the
+                            # window: wait on the admission condition (a
+                            # release anywhere re-checks), not on the pool
+                            adm.wait(0.05 if active else 0.2)
+                            emit_ready()
+                            if active:
+                                break
+                            metrics.heartbeat()
+                            continue
+                        # only the wait itself books as blocked — emission
+                        # (write + journal fsync) has its own stage, and
+                        # prep_share is the acceptance counter
+                        with metrics.timer("prep_blocked"):
+                            h = pool.get(timeout=0.05 if active else 1.0)
+                        # emit as we accumulate: instantly-done holes
+                        # (resumed/skipped) must retire HERE to keep
+                        # releasing ingest budget, or a done stretch longer
+                        # than the 4x bound live-locks against the pool
                         emit_ready()
-                        if active:
-                            break
-                        metrics.heartbeat()
-                        continue
-                    # only the wait itself books as blocked — emission
-                    # (write + journal fsync) has its own stage, and
-                    # prep_share is the acceptance counter
-                    with metrics.timer("prep_blocked"):
-                        h = pool.get(timeout=0.05 if active else 1.0)
-                    # emit as we accumulate: instantly-done holes
-                    # (resumed/skipped) must retire HERE to keep
-                    # releasing ingest budget, or a done stretch longer
-                    # than the 4x bound live-locks against the pool
-                    emit_ready()
-                    if h is None:
-                        if adm is not None:
-                            adm.release()
-                        if active:
-                            break
-                        metrics.heartbeat()
-                        continue
-                    admit(h)
+                        if h is None:
+                            if adm is not None:
+                                adm.release()
+                            if active:
+                                break
+                            metrics.heartbeat()
+                            continue
+                        admit(h)
                 # a window filled while blocked still earns growth
                 admitted_full = len(active) >= window
                 metrics.heartbeat()

@@ -17,13 +17,13 @@ so every segment starts frozen and the fused while_loop exits without
 one iteration — the execution costs ~a breakpoint scan on zeros.
 ``fn.lower(...).compile()`` would share the XLA compile but NOT the
 jit dispatch cache (measured on jax 0.4: the first real call still
-pays a retrace + cache population, which would then book as execute
-time in the tracer); the zero-slab call primes the exact fast path.
+pays a retrace + cache population on the dispatch path); the
+zero-slab call primes the exact fast path.
 
 Attribution (utils/trace.py): each builder runs inside a
 ``device_span(..., warmup=True)`` carrying the SAME group and shape
 keys the dispatch span will use, so the warmup books the (group,
-shape)'s one compile — and the first real dispatch books as execute,
+shape)'s one compile — and the first real dispatch books no compile,
 which is the trace-visible proof the overlap worked.  A warmup span
 for an already-seen shape books nothing.
 

@@ -418,10 +418,10 @@ class Metrics:
         repr=False)
     _last_interval_emit: float = dataclasses.field(
         default_factory=time.monotonic, repr=False)
-    # per-shape-group dispatch attribution (utils/trace.py fills this:
-    # compiles, compile_s, execute_s, dispatches, dp_cells per group
-    # key) — rendered into every event by snapshot() so recompile
-    # storms and slow groups are visible in any metrics JSONL
+    # per-shape-group dispatch counts (utils/trace.py fills this:
+    # compiles, dispatches, dp_cells per group key) — rendered into
+    # every event by snapshot() so recompile storms are visible in any
+    # metrics JSONL
     group_stats: dict = dataclasses.field(default_factory=dict)
     # set by the stall watchdog (utils/trace.py) when a device dispatch
     # hangs past --stall-timeout: the run completed (or died) degraded,
@@ -436,11 +436,6 @@ class Metrics:
     # same way they watch stalls; 0 = clean tree, never populated on
     # the pipeline's own hot path
     lint_findings: int = 0
-    # set by the Tracer: True when device spans used the forced-
-    # execution close (--trace), i.e. the group table's seconds are
-    # real chip walls; False means dispatch-queue bookkeeping on an
-    # async backend (counts exact, seconds unreliable)
-    groups_forced: Optional[bool] = None
     _ticked: int = 0
     t0: float = dataclasses.field(default_factory=time.monotonic)
     # emit() runs on the driver thread AND the stall-watchdog thread
@@ -736,16 +731,6 @@ class Metrics:
             snap["breaker_strike_log"] = list(self.breaker_strike_log)
         if self.group_stats:
             snap["groups"] = self._group_table()
-            snap["groups_forced"] = bool(self.groups_forced)
-            # compile share of wall: how much of this run's elapsed
-            # time went to XLA compiles (warmup-thread compiles overlap
-            # the stream, so a healthy warmed run shows compile_s high
-            # but compile blocking ~nothing — compare against the
-            # per-group tables; dict() copy: watchdog-thread safety)
-            comp = sum(st.get("compile_s", 0.0)
-                       for st in dict(self.group_stats).values())
-            snap["compile_s"] = round(comp, 4)
-            snap["compile_share"] = round(comp / self.elapsed, 4)
         if self.hists:
             snap["hist"] = self.hist_snapshot()
         if self.job:

@@ -8,7 +8,7 @@ artifact an operator actually opens before JSONL archaeology:
 * a timeline strip of the trace spans (one lane per thread, colored by
   span category, compile calls hatched out by a marker) — the
   Chrome-export view without needing Perfetto;
-* the per-shape-group compile/execute table and the per-category stage
+* the per-shape-group compile/dispatch table and the per-category stage
   self-time breakdown (both re-derived through utils/trace.summarize,
   the SAME finalizer the stats subcommand and metrics events use);
 * occupancy / fill stat tiles;
@@ -59,7 +59,7 @@ _SLOTS = (("#2a78d6", "#3987e5"), ("#eb6834", "#d95926"),
 # tests cross-check these against Metrics.snapshot())
 REPORT_TILE_KEYS = (
     "zmws_per_sec", "dp_occupancy", "dp_row_fill",
-    "packed_holes_per_dispatch", "fused_slot_fill", "compile_share",
+    "packed_holes_per_dispatch", "fused_slot_fill",
     "prep_share", "prep_overlap_share",
     "distinct_slab_shapes", "holes_filtered",
 )
@@ -369,28 +369,19 @@ def _stage_bars(stage_seconds: dict) -> str:
               "stats`)</p>")
 
 
-def _group_table(groups: dict, forced) -> str:
+def _group_table(groups: dict) -> str:
     if not groups:
         return "<p class='muted'>no shape groups in the input</p>"
-    head = ("<tr><th>group</th><th>compiles</th><th>compile_s</th>"
-            "<th>execute_s</th><th>dispatches</th><th>dp_cells</th>"
-            "<th>dp_cells/s</th></tr>")
+    head = ("<tr><th>group</th><th>compiles</th><th>dispatches</th>"
+            "<th>dp_cells</th></tr>")
     rows = []
     for key, st in sorted(groups.items()):
         warn = " class='warn'" if st.get("compiles", 0) > 2 else ""
-        cps = st.get("dp_cells_per_sec")
         rows.append(
             f"<tr{warn}><td class='mono'>{_esc(key)}</td>"
-            f"<td>{st['compiles']}</td><td>{st['compile_s']}</td>"
-            f"<td>{st['execute_s']}</td><td>{st['dispatches']}</td>"
-            f"<td>{st['dp_cells']}</td>"
-            f"<td>{cps if cps is not None else '—'}</td></tr>")
-    note = ""
-    if forced is False:
-        note = ("<p class='warn-text'>⚠ UNFORCED timing (no --trace): "
-                "per-group seconds are dispatch-queue bookkeeping on "
-                "an async backend — counts exact, rates unreliable</p>")
-    return note + "<table>" + head + "".join(rows) + "</table>"
+            f"<td>{st['compiles']}</td><td>{st['dispatches']}</td>"
+            f"<td>{st['dp_cells']}</td></tr>")
+    return "<table>" + head + "".join(rows) + "</table>"
 
 
 def _tiles(snap: dict) -> str:
@@ -525,8 +516,8 @@ def render_html(paths: List[str], title: Optional[str] = None) -> str:
 {_timeline_svg(data['spans'], data['t_end'], data['n_spans'])}</section>
 <section><h2>Stage self-time breakdown</h2>
 {_stage_bars(summary.get('stage_seconds') or {})}</section>
-<section><h2>Shape-group compile/execute table</h2>
-{_group_table(summary.get('groups') or {}, summary.get('groups_forced'))}
+<section><h2>Shape-group compile/dispatch table</h2>
+{_group_table(summary.get('groups') or {})}
 </section>
 <section><h2>Occupancy &amp; fill</h2>{_tiles(snap)}</section>
 <section><h2>Progress: ETA vs actual</h2>

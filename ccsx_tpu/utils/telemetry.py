@@ -9,7 +9,7 @@ that hung with zero live signal is exactly the gap).  Three pieces:
 
   - ``GET /metrics``  — Prometheus text format rendered from
     ``Metrics.snapshot()`` (every numeric counter, the per-shape-group
-    compile/execute table as labeled series, the progress/ETA
+    compile/dispatch counts as labeled series, the progress/ETA
     estimate, and the resource gauges);
   - ``GET /healthz``  — JSON ``ok`` (HTTP 200) or ``degraded`` (HTTP
     503, wired to the stall watchdog's mark) with the rc-relevant
@@ -81,7 +81,7 @@ PROM_GAUGES = (
     "dp_pass_fill", "dp_z_fill", "dp_row_fill", "prefilter_share",
     "packed_holes_per_dispatch", "fused_slot_fill",
     "ingest_s", "prep_s", "compute_s", "write_s", "elapsed_s",
-    "zmws_per_sec", "compile_s", "compile_share",
+    "zmws_per_sec",
     # prep plane (pipeline/prep_pool.py): critical-path prep exposure,
     # overlap quality, and the live ready-queue gauges
     "prep_blocked_s", "prep_share", "prep_overlap_share",
@@ -95,7 +95,7 @@ PROM_GAUGES = (
     "lint_findings",
 )
 # snapshot keys with dedicated (non-scalar) renderings
-PROM_STRUCTURED = ("groups", "groups_forced", "degraded", "progress",
+PROM_STRUCTURED = ("groups", "degraded", "progress",
                    "filtered_reasons", "corrupt_reasons",
                    # per-implementation banded DP-fill attribution
                    # (ccsx_banded_impl{impl=...}): scan/pallas/rotband
@@ -123,7 +123,6 @@ HIST_FAMILIES = (
     ("queue_wait_s", "size", "queue_wait_seconds"),
     ("job_wall_s", "size", "job_wall_seconds"),
     ("first_dispatch_s", "size", "first_dispatch_seconds"),
-    ("device_execute_s", "group", "device_execute_seconds"),
     ("lease_acquire_s", "kind", "lease_acquire_seconds"),
 )
 
@@ -140,8 +139,7 @@ SLO_BURN_GAUGES = (
     ("slo_job_wall_burn", "job_wall_s", 60.0, 0.99),
 )
 # per-group table fields exported as ccsx_group_<field>{group="..."}
-GROUP_FIELDS = ("compiles", "compile_s", "execute_s", "dispatches",
-                "dp_cells", "dp_cells_per_sec")
+GROUP_FIELDS = ("compiles", "dispatches", "dp_cells")
 # progress-estimator fields (Metrics.progress_snapshot)
 PROGRESS_KEYS = ("done", "total", "rate_zmws_per_sec", "elapsed_s",
                  "pct", "eta_s")
@@ -305,11 +303,7 @@ def render_prometheus(snap: dict, gauges: Optional[dict] = None) -> str:
     for gkey, st in sorted((snap.get("groups") or {}).items()):
         labels = f'{{group="{_prom_escape(gkey)}"}}'
         for f in GROUP_FIELDS:
-            sample(f"group_{f}", st.get(f), "counter"
-                   if f in ("compiles", "dispatches", "dp_cells")
-                   else "gauge", labels=labels)
-    if "groups_forced" in snap:
-        sample("groups_forced", int(bool(snap["groups_forced"])), "gauge")
+            sample(f"group_{f}", st.get(f), "counter", labels=labels)
     sample("degraded", int(bool(snap.get("degraded"))), "gauge")
     sample("native_build_error",
            int(bool(snap.get("native_build_error"))), "gauge")

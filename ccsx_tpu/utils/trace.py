@@ -1,12 +1,10 @@
-"""Dispatch flight recorder: span tracing, compile/execute attribution,
+"""Dispatch flight recorder: span tracing, per-group dispatch counts,
 and a hang watchdog.
 
-Why this exists (VERDICT r5): the framework had zero trustworthy TPU
-throughput numbers — `BENCH_r05.json` is a device attempt that hung
-with no diagnostics.  Credible DP-throughput claims need
-kernel-execute time separated from launch/compile overhead (the
-gpuPairHMM discipline, PAPERS.md), and a hang needs to leave a report
-behind.  Three pieces:
+Why this exists (VERDICT r5): `BENCH_r05.json` is a device attempt that
+hung with no diagnostics; a hang needs to leave a report behind, and
+the host's work needs names on the same clock as the device's.  Four
+pieces:
 
 * **Span tracer** (``--trace <path>``): thread-safe; every unit of work
   — ingest hole, prep batch, device dispatch, recovery rung, host
@@ -14,24 +12,26 @@ behind.  Three pieces:
   ``ts``, run-relative ``mono``, ``dur`` seconds, thread, and args.  At
   close the JSONL is additionally exported as Chrome trace-event format
   (``<path minus .jsonl>.chrome.json``), loadable in Perfetto /
-  chrome://tracing.  Device spans use the FORCED-EXECUTION close
-  discipline: the span closes only after ``jax.block_until_ready`` on
-  the dispatch outputs (``Span.force``), so a span's duration covers
-  the execution and not only the enqueue.  The force applies only
-  when a trace file is being written — an untraced run keeps the
-  dispatch-all-then-materialize overlap untouched.
+  chrome://tracing.  A device span times the host's dispatch call
+  (trace, compile on a shape's first call, enqueue), never the device:
+  dispatch is asynchronous and no span blocks on it.
 
-* **Per-shape-group attribution**: the first device span of each
+* **Profiler annotations**: every ``span``/``device_span`` also enters
+  ``jax.profiler.TraceAnnotation("ccsx.<name>")``, with or without a
+  Tracer or a trace file, so a profiler trace (``jax.profiler.trace``)
+  shows the program's spans on the device's clock.  The jitted programs
+  the dispatch sites run are named ``ccsx_*`` (the trace's "XLA
+  Modules"), and the stages of a consensus round are the named scopes
+  in ``STAGES`` (in each operation's name path on "XLA Ops").  Device
+  time per program and per stage comes from such a trace.
+
+* **Per-shape-group counts**: the first device span of each
   (group key, batch-dim shape) is a COMPILE call (XLA traces + compiles
   on first execution of a shape — including recompiles when a group's
-  bucketed batch dim changes), later spans are steady-state EXECUTE.  The table — compiles,
-  compile_s, execute_s, dispatches, dp_cells, dp_cells/s (steady-state
-  cells over execute seconds) per group — accumulates into
-  ``Metrics.group_stats`` and rides every metrics event via
-  ``Metrics.snapshot()``, so recompile storms and slow groups are
-  visible in any metrics JSONL.  Without ``--trace`` the spans are not
-  forced, so on an async backend the per-group times degrade to
-  dispatch-queue bookkeeping; the counts stay exact.
+  bucketed batch dim changes).  The table — compiles, dispatches,
+  dp_cells per group — accumulates into ``Metrics.group_stats`` and
+  rides every metrics event via ``Metrics.snapshot()``, so recompile
+  storms are visible in any metrics JSONL.
 
 * **Stall watchdog** (``--stall-timeout``, default 120 s, 0 disables):
   a daemon thread that fires when a device-dispatch span stays open
@@ -42,8 +42,8 @@ behind.  Three pieces:
   degraded (``Metrics.degraded``, carried by every later event incl.
   final).  The watchdog needs no trace file: span open/close tracking
   around dispatches is always on (two perf_counter reads), and since
-  an UNFORCED dispatch span closes in ~1 ms on an async runtime with
-  the hang surfacing later, the executors' finish phase runs inside a
+  a dispatch span closes in ~1 ms on an async runtime with the hang
+  surfacing later, the executors' finish phase runs inside a
   watchdog-visible ``materialize`` device span (``attribute=False`` —
   timeline-only, never in the group table) — so the next "tpu attempt
   hung" produces an actionable report whichever side it hangs on.
@@ -57,7 +57,7 @@ and the top-N slowest dispatches (``stats_main`` below).
 Wiring: the drivers construct a Tracer next to their Metrics and
 ``install()`` it process-globally for the run; call sites use the
 module-level ``span`` / ``device_span`` / ``instant`` helpers, which
-no-op (cheaply) when nothing is installed.
+only annotate for the profiler when nothing is installed.
 """
 
 from __future__ import annotations
@@ -96,6 +96,16 @@ RESILIENCE_KEYS = ("device_hangs", "breaker_state", "breaker_trips",
                    "breaker_probes", "host_fallbacks", "oom_resplits",
                    "compile_fallbacks", "holes_failed", "holes_corrupt",
                    "stalls")
+
+# the named scopes (jax.named_scope) of a consensus round's stages, in
+# round order: the banded DP fill, the traceback projection, the column
+# vote with the in-loop draft rebuild, and the breakpoint scan.  A
+# profiler trace finds each stage's device time by these names in its
+# operations' name paths (benchmarks/ccsbench/stages.py)
+STAGES = ("fill", "traceback", "vote", "breakpoint")
+
+# every span's profiler annotation is named ANNOTATION_PREFIX + name
+ANNOTATION_PREFIX = "ccsx."
 
 _current: Optional["Tracer"] = None
 
@@ -176,30 +186,27 @@ def current() -> Optional["Tracer"]:
     return _current
 
 
-class _NullSpan:
-    """The no-op span: force() is the identity, so call sites can write
-    ``return sp.force(step(...))`` unconditionally."""
-
-    __slots__ = ()
-
-    def force(self, out):
-        return out
-
-
-_NULL_SPAN = _NullSpan()
-
-
-@contextlib.contextmanager
-def _null_ctx():
-    yield _NULL_SPAN
+def annotation(name: str, args: Optional[dict] = None):
+    """The profiler's view of a span: a ``TraceAnnotation`` named
+    ``ccsx.<name>`` on this thread.  No profiler session can record
+    before JAX is imported, so a process without it gets a no-op;
+    otherwise an inactive profiler costs the annotation's one check,
+    and ``args`` become its metadata only while a session records (no
+    string is formatted on the hot path)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    ann = jax.profiler.TraceAnnotation
+    if args and ann.is_enabled():
+        return ann(ANNOTATION_PREFIX + name, **args)
+    return ann(ANNOTATION_PREFIX + name)
 
 
 class Span:
-    __slots__ = ("tracer", "sid", "name", "cat", "args", "t0", "ts",
+    __slots__ = ("sid", "name", "cat", "args", "t0", "ts",
                  "tid", "cid", "reported", "grace")
 
-    def __init__(self, tracer, sid, name, cat, args):
-        self.tracer = tracer
+    def __init__(self, sid, name, cat, args):
         self.sid = sid
         self.name = name
         self.cat = cat
@@ -215,21 +222,9 @@ class Span:
         self.grace = 1.0        # stall-timeout multiplier (COMPILE_GRACE
         #   for first-of-shape device spans; set by device_span)
 
-    def force(self, out):
-        """Forced-execution close: block until the device work of this
-        span's dispatch actually ran (lazy runtimes otherwise return
-        unexecuted handles; see module docstring).  Applied only when a
-        trace file is recording — watchdog-only runs keep the async
-        dispatch overlap."""
-        if self.tracer is not None and self.tracer.forced:
-            import jax
-
-            jax.block_until_ready(out)
-        return out
-
 
 class Tracer:
-    """Thread-safe span recorder + group attribution + stall watchdog.
+    """Thread-safe span recorder + group counts + stall watchdog.
 
     ``path=None`` runs watchdog/attribution only (no records written);
     ``stall_timeout=0`` disables the watchdog.  ``metrics`` (optional)
@@ -242,16 +237,10 @@ class Tracer:
         self.path = path or None
         self.stall_timeout = max(float(stall_timeout or 0.0), 0.0)
         self.metrics = metrics
-        self.forced = self.path is not None
         # the group table lives on the Metrics object when there is one,
         # so Metrics.snapshot() carries it without a back-reference
         self.group_stats: Dict[str, dict] = (
             metrics.group_stats if metrics is not None else {})
-        if metrics is not None:
-            # published alongside the table: unforced per-group seconds
-            # are dispatch-queue bookkeeping on an async backend, and a
-            # consumer must be able to tell that from forced evidence
-            metrics.groups_forced = self.forced
         self.stalled = False
         self._stall_dumps = 0      # reports so far (rate-limit state)
         self._last_full_dump = -float("inf")
@@ -339,69 +328,70 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "host", **args):
-        """A plain (non-device) span; records only when a trace file is
-        open or the blackbox ring is armed (CCSX_BLACKBOX)."""
-        if self._f is None and blackbox.get() is None:
-            yield _NULL_SPAN
-            return
-        sp = Span(self, -1, name, cat, args)
-        self._push()
-        try:
-            yield sp
-        except StopIteration:
-            # generator-protocol control flow (a driver's span around
-            # next(stream) hitting EOF), not an error
-            raise
-        except BaseException:
-            sp.args = dict(sp.args, error=True)
-            raise
-        finally:
-            dur = time.perf_counter() - sp.t0
-            self_s = self._pop(dur)
-            rec = self._span_rec(sp, dur)
-            if self_s < dur - 1e-9:    # had children: carry self time
-                rec["self"] = round(self_s, 6)
-            self._write(rec)
+        """A plain (non-device) span: always a profiler annotation;
+        records only when a trace file is open or the blackbox ring is
+        armed (CCSX_BLACKBOX)."""
+        with annotation(name, args):
+            if self._f is None and blackbox.get() is None:
+                yield
+                return
+            sp = Span(-1, name, cat, args)
+            self._push()
+            try:
+                yield
+            except StopIteration:
+                # generator-protocol control flow (a driver's span
+                # around next(stream) hitting EOF), not an error
+                raise
+            except BaseException:
+                sp.args = dict(sp.args, error=True)
+                raise
+            finally:
+                dur = time.perf_counter() - sp.t0
+                self_s = self._pop(dur)
+                rec = self._span_rec(sp, dur)
+                if self_s < dur - 1e-9:    # had children: carry self
+                    rec["self"] = round(self_s, 6)
+                self._write(rec)
 
     @contextlib.contextmanager
     def device_span(self, name: str, group: Optional[str] = None,
                     cells: int = 0, plan=None, shape=None,
                     attribute: bool = True, warmup: bool = False,
                     **args):
-        """A device-dispatch span: watchdog-registered while open,
-        compile/execute-attributed at close.  ``group`` keys the
-        attribution table; ``cells`` is the dispatched DP cell count
-        (feeds dp_cells/s); ``plan`` is the free-form slab/shape plan
-        the watchdog dumps when the span stalls.  ``shape`` is the part
-        of the dispatched shape the group key does NOT carry (e.g. the
+        """A device-dispatch span: a profiler annotation, watchdog-
+        registered while open, counted in the group table at close.
+        ``group`` keys the table; ``cells`` is the dispatched DP cell
+        count; ``plan`` is the free-form slab/shape plan the watchdog
+        dumps when the span stalls.  ``shape`` is the part of the
+        dispatched shape the group key does NOT carry (e.g. the
         bucketed batch dim Z/R/N): jit recompiles per distinct shape,
-        so compile-vs-execute is detected per (group, shape) — a group
-        whose batch dim oscillates shows compiles > 1 instead of
-        booking the recompiles as execute time.  A dispatch that raises
-        is recorded (error=true) but NOT attributed: the recovery
-        ladder re-dispatches the work, and counting both the failed
-        attempt and its retried halves would double-count cells.
+        so a compile is detected per (group, shape) — a group whose
+        batch dim oscillates shows compiles > 1.  A dispatch that raises
+        is recorded (error=true) but NOT counted: the recovery ladder
+        re-dispatches the work, and counting both the failed attempt
+        and its retried halves would double-count cells.
 
         ``attribute=False`` makes a watchdog-visible span that stays
         OUT of the group table — the finish-phase materialization span:
-        on an async runtime an untraced (unforced) dispatch span closes
-        in ~1 ms and the actual hang surfaces later, when the finish
-        callback blocks materializing the outputs, so that blocking
-        wait must itself be a device span or the watchdog is blind to
-        exactly the r5 hang.  Attribution convention: only
-        records carrying a "compile" key (true or false) enter group
-        tables — failed and attribute=False spans carry none.
+        on an async runtime a dispatch span closes in ~1 ms and the
+        actual hang surfaces later, when the finish callback blocks
+        materializing the outputs, so that blocking wait must itself be
+        a device span or the watchdog is blind to exactly the r5 hang.
+        Attribution convention: only records carrying a "compile" key
+        (true or false) enter group tables — failed and attribute=False
+        spans carry none.
 
         ``warmup=True`` marks an AOT precompile span (pipeline/
         warmup.py): it consumes the (group, shape)'s compile slot — so
-        the first REAL dispatch of a warmed shape books as execute,
-        the trace-visible proof the compile overlapped the stream —
-        and books compiles/compile_s in the group table WITHOUT
-        counting a dispatch or cells (nothing was dispatched for a
-        consumer).  A warmup span for an already-seen shape books
-        nothing.  Warmup records carry top-level "warmup": true next
-        to the "compile" key; the stats re-derivation applies the same
-        rule (summarize)."""
+        the first REAL dispatch of a warmed shape books no compile, the
+        trace-visible proof the compile overlapped the stream — and
+        counts the compile in the group table WITHOUT counting a
+        dispatch or cells (nothing was dispatched for a consumer).  A
+        warmup span for an already-seen shape books nothing.  Warmup
+        records carry top-level "warmup": true next to the "compile"
+        key; the stats re-derivation applies the same rule
+        (summarize)."""
         a = dict(args)
         key = group or name
         a["group"] = key
@@ -411,93 +401,76 @@ class Tracer:
             a["shape"] = shape
         if plan is not None:
             a["plan"] = plan
-        with self._lock:
-            self._sid += 1
-            sid = self._sid
-        sp = Span(self, sid, name, "device", a)
-        with self._lock:
-            # first span of a (group, shape) is the compile candidate:
-            # it gets COMPILE_GRACE x the stall timeout (a cold compile
-            # can take minutes and is not a hang)
-            gkey = (key, shape)
-            if gkey not in self._grace_seen:
-                self._grace_seen.add(gkey)
-                sp.grace = COMPILE_GRACE
-            self._open[sid] = sp
-        # span-BEGIN mirror, ring only: a SIGKILL mid-dispatch never
-        # reaches the close record below, so the begin entry is the
-        # ONLY evidence of what was in flight — inflight() pairs it
-        # with the close by (tid, name)
-        bb = blackbox.get()
-        if bb is not None:
-            brec = {"ev": "begin", "name": name, "group": key,
-                    "ts": round(sp.ts, 6), "tid": sp.tid}
-            if shape is not None:
-                brec["shape"] = str(shape)
-            if sp.cid is not None:
-                brec["cid"] = sp.cid
-            bb.record(brec)
-        pushed = self._f is not None
-        if pushed:
-            self._push()
-        failed = False
-        try:
-            yield sp
-        except BaseException:
-            failed = True
-            sp.args = dict(sp.args, error=True)
-            raise
-        finally:
-            dur = time.perf_counter() - sp.t0
-            # device spans are normally leaves (self == dur), but keep
-            # the accounting honest if one ever acquires children
-            self_s = self._pop(dur) if pushed else dur
-            first = False
-            executed = False
+        with annotation(name, a):
             with self._lock:
-                self._open.pop(sid, None)
-                if attribute and not failed:
-                    skey = (key, shape)
-                    first = skey not in self._seen
-                    self._seen.add(skey)
-                    st = self.group_stats.setdefault(key, {
-                        "compiles": 0, "compile_s": 0.0,
-                        "execute_s": 0.0, "dispatches": 0,
-                        "dp_cells": 0, "exec_cells": 0})
-                    if warmup:
-                        # AOT precompile: books the shape's one compile,
-                        # no dispatch/cells; a redundant warmup of a
-                        # seen shape books nothing at all
+                self._sid += 1
+                sid = self._sid
+            sp = Span(sid, name, "device", a)
+            with self._lock:
+                # first span of a (group, shape) is the compile
+                # candidate: it gets COMPILE_GRACE x the stall timeout
+                # (a cold compile can take minutes and is not a hang)
+                gkey = (key, shape)
+                if gkey not in self._grace_seen:
+                    self._grace_seen.add(gkey)
+                    sp.grace = COMPILE_GRACE
+                self._open[sid] = sp
+            # span-BEGIN mirror, ring only: a SIGKILL mid-dispatch never
+            # reaches the close record below, so the begin entry is the
+            # ONLY evidence of what was in flight — inflight() pairs it
+            # with the close by (tid, name)
+            bb = blackbox.get()
+            if bb is not None:
+                brec = {"ev": "begin", "name": name, "group": key,
+                        "ts": round(sp.ts, 6), "tid": sp.tid}
+                if shape is not None:
+                    brec["shape"] = str(shape)
+                if sp.cid is not None:
+                    brec["cid"] = sp.cid
+                bb.record(brec)
+            pushed = self._f is not None
+            if pushed:
+                self._push()
+            failed = False
+            try:
+                yield
+            except BaseException:
+                failed = True
+                sp.args = dict(sp.args, error=True)
+                raise
+            finally:
+                dur = time.perf_counter() - sp.t0
+                # device spans are normally leaves (self == dur), but
+                # keep the accounting honest if one acquires children
+                self_s = self._pop(dur) if pushed else dur
+                first = False
+                with self._lock:
+                    self._open.pop(sid, None)
+                    if attribute and not failed:
+                        skey = (key, shape)
+                        first = skey not in self._seen
+                        self._seen.add(skey)
+                        st = self.group_stats.setdefault(key, {
+                            "compiles": 0, "dispatches": 0,
+                            "dp_cells": 0})
+                        # the first call of a (group, shape) traces and
+                        # compiles; a warmup books only that compile, a
+                        # redundant warmup of a seen shape nothing
                         if first:
                             st["compiles"] += 1
-                            st["compile_s"] += dur
-                    else:
-                        st["dispatches"] += 1
-                        st["dp_cells"] += int(cells or 0)
-                        if first:
-                            # first call of a (group, shape) = XLA trace
-                            # + compile + execute; later calls are
-                            # steady-state execute
-                            st["compiles"] += 1
-                            st["compile_s"] += dur
-                        else:
-                            st["execute_s"] += dur
-                            st["exec_cells"] += int(cells or 0)
-                            executed = True
-            if executed and self.metrics is not None:
-                # per-group device-execute latency distribution
-                # (steady-state only: compile calls would put the XLA
-                # compile wall in the execute histogram)
-                self.metrics.observe("device_execute_s", dur, key)
-            if failed or not attribute:
-                rec = self._span_rec(sp, dur)
-            elif warmup:
-                rec = self._span_rec(sp, dur, compile=first, warmup=True)
-            else:
-                rec = self._span_rec(sp, dur, compile=first)
-            if self_s < dur - 1e-9:
-                rec["self"] = round(self_s, 6)
-            self._write(rec)
+                        if not warmup:
+                            st["dispatches"] += 1
+                            st["dp_cells"] += int(cells or 0)
+                if failed or not attribute:
+                    rec = self._span_rec(sp, dur)
+                elif warmup:
+                    rec = self._span_rec(sp, dur, compile=first,
+                                         warmup=True)
+                else:
+                    rec = self._span_rec(sp, dur, compile=first)
+                if self_s < dur - 1e-9:
+                    rec["self"] = round(self_s, 6)
+                self._write(rec)
 
     def instant(self, name: str, cat: str = "host", **args) -> None:
         """A zero-duration marker (Chrome 'instant' event)."""
@@ -624,12 +597,13 @@ class Tracer:
                   file=sys.stderr)
 
 
-# ---- module-level shims (no-ops when no tracer is installed) --------------
+# ---- module-level shims (profiler annotations only when no tracer is
+# ---- installed) ------------------------------------------------------------
 
 def span(name: str, cat: str = "host", **args):
     t = _current
     if t is None:
-        return _null_ctx()
+        return annotation(name, args)
     return t.span(name, cat, **args)
 
 
@@ -637,7 +611,7 @@ def device_span(name: str, group: Optional[str] = None, cells: int = 0,
                 plan=None, warmup: bool = False, **args):
     t = _current
     if t is None:
-        return _null_ctx()
+        return annotation(name, dict(args, group=group or name))
     return t.device_span(name, group=group, cells=cells, plan=plan,
                          warmup=warmup, **args)
 
@@ -728,27 +702,16 @@ def export_chrome(path: str) -> str:
 
 
 def finalize_group_table(raw: Dict[str, dict]) -> dict:
-    """Render raw per-group accumulators (compiles/compile_s/execute_s/
-    dispatches/dp_cells/exec_cells) for output: rounded seconds plus
-    the steady-state dp_cells_per_sec rate (compile-call cells excluded
-    — the first call of a shape pays the XLA compile, so dividing its
-    cells by its wall time would understate the chip).  THE one
-    finalizer: Metrics._group_table (metrics events) and summarize()
-    (trace files) both call it, so the 'same' table from either source
-    cannot drift."""
-    out = {}
-    for key, st in sorted(raw.items()):
-        ex = st["execute_s"]
-        out[key] = {
-            "compiles": st["compiles"],
-            "compile_s": round(st["compile_s"], 4),
-            "execute_s": round(ex, 4),
-            "dispatches": st["dispatches"],
-            "dp_cells": st["dp_cells"],
-            "dp_cells_per_sec": round(st["exec_cells"] / ex)
-                                if ex > 0 else None,
-        }
-    return out
+    """Render raw per-group counts (compiles/dispatches/dp_cells) for
+    output, sorted by group.  THE one finalizer: Metrics._group_table
+    (metrics events) and summarize() (trace files) both call it, so the
+    'same' table from either source cannot drift.  Device seconds per
+    program come from a profiler trace (module docstring), not from
+    the host clock around an asynchronous dispatch."""
+    return {key: {"compiles": st["compiles"],
+                  "dispatches": st["dispatches"],
+                  "dp_cells": st["dp_cells"]}
+            for key, st in sorted(raw.items())}
 
 
 # ---- `ccsx-tpu stats`: summarize trace/metrics JSONL artifacts ------------
@@ -818,25 +781,16 @@ def summarize(paths, top: int = 10) -> dict:
                     continue
                 key = str(sp.get("args", {}).get("group", sp["name"]))
                 st = groups.setdefault(key, {
-                    "compiles": 0, "compile_s": 0.0, "execute_s": 0.0,
-                    "dispatches": 0, "dp_cells": 0, "exec_cells": 0})
+                    "compiles": 0, "dispatches": 0, "dp_cells": 0})
+                if sp["compile"]:
+                    st["compiles"] += 1
                 if sp.get("warmup"):
                     # AOT warmup span (pipeline/warmup.py): the shape's
                     # compile, no dispatch — same rule device_span
                     # applied to Metrics.group_stats
-                    if sp["compile"]:
-                        st["compiles"] += 1
-                        st["compile_s"] += sp["dur"]
                     continue
                 st["dispatches"] += 1
-                cells = int(sp.get("args", {}).get("cells", 0))
-                st["dp_cells"] += cells
-                if sp["compile"]:
-                    st["compiles"] += 1
-                    st["compile_s"] += sp["dur"]
-                else:
-                    st["execute_s"] += sp["dur"]
-                    st["exec_cells"] += cells
+                st["dp_cells"] += int(sp.get("args", {}).get("cells", 0))
     groups = finalize_group_table(groups)
 
     mrec = final or last_metrics
@@ -854,13 +808,9 @@ def summarize(paths, top: int = 10) -> dict:
                 mrec["breaker_strike_log"]
     slowest = [e for _, _, e in
                sorted(slow_heap, key=lambda t: (-t[0], t[1]))]
-    # a table built from span records came from a forced (--trace) run;
-    # one inherited from a metrics file carries that file's discipline
-    forced = True if groups else (mrec or {}).get("groups_forced")
     return {
         "paths": list(paths),
         "groups": groups or (mrec or {}).get("groups") or {},
-        "groups_forced": forced,
         "stage_seconds": {k: round(v, 4)
                           for k, v in sorted(stages.items())},
         "slowest": slowest,
@@ -878,21 +828,12 @@ def format_summary(d: dict) -> str:
     lines.append(f"spans: {d['n_spans']}")
     if d["groups"]:
         lines.append("shape groups:")
-        if d.get("groups_forced") is False:
-            lines.append("  !! UNFORCED timing (no --trace): per-group "
-                         "seconds are dispatch-queue bookkeeping on an "
-                         "async backend — counts exact, rates unreliable")
-        hdr = (f"  {'group':<40} {'compiles':>8} {'compile_s':>10} "
-               f"{'execute_s':>10} {'disp':>6} {'dp_cells':>14} "
-               f"{'dp_cells/s':>12}")
-        lines.append(hdr)
+        lines.append(f"  {'group':<40} {'compiles':>8} {'disp':>6} "
+                     f"{'dp_cells':>14}")
         for key, st in sorted(d["groups"].items()):
-            cps = st.get("dp_cells_per_sec")
             lines.append(
                 f"  {key:<40} {st['compiles']:>8} "
-                f"{st['compile_s']:>10.4f} {st['execute_s']:>10.4f} "
-                f"{st['dispatches']:>6} {st['dp_cells']:>14} "
-                f"{cps if cps is not None else '-':>12}")
+                f"{st['dispatches']:>6} {st['dp_cells']:>14}")
         # compile-storm guard (the r7 finding: packed groups paying 4-5
         # compiles each, one per distinct tail-slab R, invisible until
         # traced).  Canonical slab shapes bound a packed group to the

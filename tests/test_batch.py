@@ -356,3 +356,40 @@ def test_pass_buckets_knob_output_invariant(tmp_path, rng):
 def test_pass_buckets_bad_value_rejected(capsys):
     assert cli.main(["--pass-buckets", "8,4", "in.fa", "out.fa"]) == 1
     assert "--pass-buckets" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("refine", [False, True])
+@pytest.mark.parametrize("pack", [False, True])
+def test_bucketed_steps_name_their_program_and_stages(refine, pack):
+    """Lowered, not compiled: the bucketed round and refine programs,
+    packed (one device) or not (a mesh), are named for their dispatch
+    site and carry the four stage scopes."""
+    import jax
+    import jax.numpy as jnp
+
+    from ccsx_tpu.pipeline import batch as bm
+    from ccsx_tpu.utils import trace
+
+    cfg = CcsConfig(is_bam=False)
+    Z, P, qmax, tmax = 2, 4, 128, 256
+    bp = BatchExecutor(cfg)._bp_consts()
+    packing = (P, qmax) if pack else None
+    if refine:
+        step = bm._refine_step(cfg.align, cfg.max_ins_per_col, tmax, 2, bp,
+                               pack=packing)
+    else:
+        step = bm._round_step(cfg.align, cfg.max_ins_per_col, tmax, bp,
+                              pack=packing)
+    S = jax.ShapeDtypeStruct
+    if pack:
+        args = (S((Z, P * qmax + tmax), jnp.uint8),
+                S((Z, 2 * P + 1), jnp.int32))
+    else:
+        args = (S((Z, P, qmax), jnp.uint8), S((Z, P), jnp.int32),
+                S((Z, tmax), jnp.uint8), S((Z,), jnp.int32),
+                S((Z, P), jnp.bool_))
+    text = step.lower(*args).as_text(debug_info=True)
+    name = "ccsx_refine" if refine else "ccsx_round"
+    assert f"module @jit_{name} " in text
+    for stage in trace.STAGES:
+        assert f"/{stage}/" in text, stage

@@ -256,7 +256,7 @@ def test_warmup_first_dispatch_books_execute(rng, tmp_path):
               if k.startswith("packed:")}
     for key, st in packed.items():
         assert st["compiles"] >= 1
-        assert st["execute_s"] > 0
+        assert st["dispatches"] >= 1
     # stats' summarize() applies the same warmup rule: the re-derived
     # table must agree with the live one on compiles and dispatches
     summ = trace.summarize([p])
@@ -335,11 +335,10 @@ def test_stats_compile_storm_warning():
     """`ccsx-tpu stats` renders the loud compiles>1 warning (the r7
     storm guard) and stays quiet on a clean table."""
     def summary(compiles):
-        return {"paths": ["t.jsonl"], "n_spans": 1, "groups_forced": True,
+        return {"paths": ["t.jsonl"], "n_spans": 1,
                 "groups": {"packed:q512:t1024:i2": {
-                    "compiles": compiles, "compile_s": 1.0,
-                    "execute_s": 2.0, "dispatches": 5,
-                    "dp_cells": 10, "dp_cells_per_sec": 5}},
+                    "compiles": compiles, "dispatches": 5,
+                    "dp_cells": 10}},
                 "stage_seconds": {}, "slowest": [], "occupancy": {},
                 "stalls": [], "degraded": None}
 
@@ -512,6 +511,5 @@ def test_compile_budget_scale64(tmp_path, rng):
         f"more XLA programs than canonical heights: "
         f"{total_c}/{len(packed)} groups (ladder {ladder})")
     assert final["distinct_slab_shapes"] is not None
-    assert final["compile_share"] is not None
     assert final.get("degraded") is None
     assert out.read_text().count(">mv/") >= 60

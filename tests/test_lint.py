@@ -80,8 +80,6 @@ CORPUS = [
     ("concurrency/metrics_bad.py", "contextvar-restore", 1),
     ("concurrency/metrics_ok.py", "metrics-lock", 0),
     ("concurrency/metrics_ok.py", "contextvar-restore", 0),
-    ("spans/span_bad.py", "span-force", 1),
-    ("spans/span_ok.py", "span-force", 0),
 ]
 
 
@@ -120,7 +118,7 @@ def test_pragma_suppresses_only_named_check(tmp_path):
         "def set_only(x):\n"
         "    _v.set(x)  # lint: ok[contextvar-restore] fixture pragma\n\n\n"
         "def set_wrong_id(x):\n"
-        "    _v.set(x)  # lint: ok[span-force] wrong id\n"
+        "    _v.set(x)  # lint: ok[bare-write] wrong id\n"
     )
     p = tmp_path / "mod.py"
     p.write_text(src)

@@ -6,6 +6,8 @@ The packer's own invariants live in the fast unit tier
 (tests/test_pack.py); here the packed DEVICE path is differential-tested
 — the acceptance pin that lets packing be the batched default."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -169,3 +171,39 @@ def test_cli_packed_equals_bucketed_equals_per_hole(tmp_path, rng):
     assert finals["packed"]["dp_row_fill"] is not None
     assert finals["packed"]["packed_holes_per_dispatch"] >= 1
     assert finals["bucketed"]["dp_row_fill"] is None  # control ran bucketed
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_packed_refine_step_names_its_program_and_stages(fused):
+    """Lowered, not compiled: the packed refine program is named for
+    its dispatch site (the profiler trace's "XLA Modules"), and its
+    operations carry the four stage scopes (their op-name paths)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ccsx_tpu.parallel.mesh import build_slab_mesh
+    from ccsx_tpu.pipeline import batch as bm
+    from ccsx_tpu.utils import trace
+
+    cfg = CcsConfig(is_bam=False)
+    R, qmax, tmax, H = 8, 128, 256, 2
+    Lbig, Lsmall = bm._slab_wire_sizes(R, qmax, H, tmax,
+                                       cfg.max_ins_per_col)
+    bp = BatchExecutor(cfg)._bp_consts()
+    lead = ()
+    if fused:
+        step = bm._refine_step_packed_fused(
+            cfg.align, cfg.max_ins_per_col, tmax, 2, H, bp, (R, qmax),
+            build_slab_mesh(jax.devices()[:2]))
+        lead = (2,)
+    else:
+        step = bm._refine_step_packed(cfg.align, cfg.max_ins_per_col,
+                                      tmax, 2, H, bp, pack=(R, qmax))
+    text = step.lower(jax.ShapeDtypeStruct(lead + (Lbig,), jnp.uint8),
+                      jax.ShapeDtypeStruct(lead + (Lsmall,), jnp.int32)
+                      ).as_text(debug_info=True)
+    name = "ccsx_refine_packed_fused" if fused else "ccsx_refine_packed"
+    assert f"module @jit_{name} " in text
+    for stage in trace.STAGES:
+        # a part of an op-name path; 'vmap(<scope>)' under the fused vmap
+        assert re.search(rf"[/(]{stage}[)/]", text), stage

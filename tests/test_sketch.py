@@ -340,3 +340,26 @@ def test_warm_covers_prefilter_shapes(rng):
     assert "seed_device" not in kinds2
 
 
+
+
+@pytest.mark.parametrize("site", ["ccsx_pair_fill", "ccsx_prefilter",
+                                  "ccsx_seed"])
+def test_prep_steps_name_their_program(site):
+    """Lowered, not compiled: each prep dispatch site's program is named
+    for it in a profiler trace's "XLA Modules"."""
+    import jax
+    import jax.numpy as jnp
+
+    from ccsx_tpu.pipeline import batch as bm
+
+    qmax = tmax = 128
+    step, width = {
+        "ccsx_pair_fill": (lambda: bm._pair_fill_packed(
+            AlignParams(), qmax, tmax), 6),
+        "ccsx_prefilter": (lambda: sketch.screen_step(qmax, tmax), 2),
+        "ccsx_seed": (lambda: seed_device.seed_step(qmax, tmax), 2),
+    }[site]
+    text = step().lower(jax.ShapeDtypeStruct((4, qmax + tmax), jnp.uint8),
+                        jax.ShapeDtypeStruct((4, width), jnp.int32)
+                        ).as_text()
+    assert f"module @jit_{site} " in text

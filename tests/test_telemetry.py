@@ -305,7 +305,7 @@ def test_report_golden_structure_on_r8_artifacts(tmp_path, capsys):
     assert page.startswith("<!DOCTYPE html>")
     # sections
     for section in ("Timeline", "Stage self-time breakdown",
-                    "Shape-group compile/execute table",
+                    "Shape-group compile/dispatch table",
                     "Occupancy &amp; fill", "Progress: ETA vs actual",
                     "Stall &amp; recovery log"):
         assert section in page, section
@@ -394,9 +394,8 @@ def _populated_snapshot():
     m.degraded = "x"
     m.breaker_state = "open"
     m.breaker_strike_log = [{"ts": 1.0, "kind": "hang", "group": "g"}]
-    m.group_stats["g"] = {"compiles": 1, "compile_s": 0.1,
-                          "execute_s": 0.2, "dispatches": 3,
-                          "dp_cells": 40, "exec_cells": 30}
+    m.group_stats["g"] = {"compiles": 1, "dispatches": 3,
+                          "dp_cells": 40}
     m.job = "j0007"
     m.cid = "cfeedfacecafe"
     # one observation into EVERY latency family, so the key-set guards
@@ -404,7 +403,6 @@ def _populated_snapshot():
     m.observe("queue_wait_s", 0.3, "small")
     m.observe("job_wall_s", 70.0, "large")
     m.observe("first_dispatch_s", 0.1, "small")
-    m.observe("device_execute_s", 0.02, "g")
     m.observe("lease_acquire_s", 0.001, "job")
     return m.snapshot()
 
@@ -505,7 +503,7 @@ def test_prometheus_histogram_exposition_wellformed():
         name, _, value = line.rpartition(" ")
         samples[name] = float(value)
     labels = {"queue_wait_s": "small", "job_wall_s": "large",
-              "first_dispatch_s": "small", "device_execute_s": "g",
+              "first_dispatch_s": "small",
               "lease_acquire_s": "job"}
     for fam, label_key, prom in telemetry.HIST_FAMILIES:
         assert f"# TYPE ccsx_{prom} histogram" in text, prom
@@ -576,11 +574,11 @@ def test_merge_hists_folds_job_snapshot_into_core():
     core, job = Metrics(), Metrics()
     core.observe("first_dispatch_s", 0.1, "small")
     job.observe("first_dispatch_s", 0.2, "small")
-    job.observe("device_execute_s", 0.05, "g")
+    job.observe("lease_acquire_s", 0.05, "job")
     core.merge_hists(job.hist_snapshot())
     snap = core.hist_snapshot()
     assert snap["first_dispatch_s"]["small"]["count"] == 2
-    assert snap["device_execute_s"]["g"]["count"] == 1
+    assert snap["lease_acquire_s"]["job"]["count"] == 1
     core.merge_hists({"first_dispatch_s": {"small": {"bad": 1}},
                       "junk": "x"})     # malformed entries are skipped
     assert core.hist_snapshot()["first_dispatch_s"]["small"]["count"] == 2
