@@ -587,9 +587,9 @@ def measure():
     params = AlignParams()
     projector = traceback.make_projector(W, 4)
     voter = msa.make_voter(4)
-    # the production aligner dispatch: the vmapped lax.scan fill by
-    # default on every backend; CCSX_BANDED_IMPL=pallas|rotband selects
-    # a kernel for A/B runs
+    # the production aligner dispatch: the v1 Pallas fill on a TPU at
+    # qmax <= 4096, the vmapped lax.scan elsewhere
+    # (star.banded_impl_effective); CCSX_BANDED_IMPL forces one for A/B
     aligner = star._aligner(params)
 
     def round_core(qs, qlens, ts, tlens, row_mask):

@@ -124,7 +124,7 @@ def _round_step(impl: str, W: int):
     projector = traceback.make_projector(W, 4)
     voter = msa.make_voter(4)
     # NOTE: the impl dispatch happens at TRACE time (star._aligner reads
-    # use_pallas() when the jitted step first runs).  The caller
+    # banded_impl_effective() when the jitted step first runs).  The caller
     # (time_impl) holds the CCSX_BANDED_IMPL override through trace/compile,
     # which is when tracing occurs — do not call the returned step
     # outside such a scope or the wrong impl gets traced and cached.

@@ -142,7 +142,7 @@ def main():
     res = {
         "backend": jax.default_backend(),
         "shapes": {"Z": Z, "P": P, "W": W, "tlen": TLEN, "band": 128},
-        "banded_impl": "pallas" if star.use_pallas() else "scan",
+        "banded_impl": star.banded_impl_effective(W),
         "projector_impl": os.environ.get("CCSX_PROJECTOR", "") or "walk",
         "stage_seconds": {
             "fill": round(t_fill, 6),

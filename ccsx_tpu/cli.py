@@ -116,12 +116,13 @@ TPU extensions (long options):
                            driver thread, the old behavior; output
                            bytes identical either way) [auto]
 --banded-impl {scan,pallas,rotband}
-                          (banded DP-fill implementation: the lax.scan
+                          (force the banded DP fill: the lax.scan
                            spec, the v1 band-local Pallas kernel, or
                            the v2 rotating-band kernel — all three
-                           bit-identical (the A/B knob the promotion
-                           harness benchmarks/pallas_ab.py drives);
-                           also settable as CCSX_BANDED_IMPL) [scan]
+                           bit-identical, so a pure A/B knob; also
+                           settable as CCSX_BANDED_IMPL.  Unset: v1 on
+                           a TPU at qmax <= 4096 outside --mesh, else
+                           the scan; 2.4x the scan's speed on a v5e)
 --prefilter {on,off}      (device pre-alignment screen: one batched
                            dispatch scores each wave of strand_match
                            pair candidates and rejects hopeless ones
@@ -326,13 +327,17 @@ def build_parser() -> argparse.ArgumentParser:
                         "[auto-size to the host]")
     p.add_argument("--banded-impl", default="", dest="banded_impl",
                    choices=["", "scan", "pallas", "rotband"],
-                   help="banded DP-fill implementation (consensus/"
-                        "star.banded_impl): 'scan' = the lax.scan spec "
-                        "(default), 'pallas' = the v1 band-local "
-                        "kernel, 'rotband' = the v2 rotating-band "
-                        "kernel.  Bit-identical output either way "
-                        "(pinned); a pure performance A/B knob.  Also "
-                        "settable as CCSX_BANDED_IMPL [scan]")
+                   help="force the banded DP fill (consensus/"
+                        "star.banded_impl): 'scan' = the lax.scan spec, "
+                        "'pallas' = the v1 band-local kernel, "
+                        "'rotband' = the v2 rotating-band kernel.  "
+                        "Bit-identical output either way (pinned); a "
+                        "pure performance A/B knob.  Also settable as "
+                        "CCSX_BANDED_IMPL.  Unset: the v1 kernel on a "
+                        "TPU where qmax <= 4096 and the step is not "
+                        "--mesh partitioned, else the scan (an N=128, "
+                        "qmax 4096 fill on a v5e: v1 0.77 s, rotband "
+                        "1.68 s, scan 1.84 s)")
     p.add_argument("--prefilter", default="on", choices=["on", "off"],
                    dest="prefilter",
                    help="device pre-alignment screen (ops/sketch.py): "

@@ -187,9 +187,10 @@ class CcsConfig:
     # ---- device/mesh ----
     device: str = "auto"               # {auto, tpu, cpu}
     banded_impl: str = ""              # CLI --banded-impl: banded DP-fill
-    #   implementation {scan, pallas, rotband}; "" = scan (the spec).
-    #   All three are bit-identical (consensus/star.banded_impl docstring
-    #   has the promotion protocol) — a pure performance A/B knob, so it
+    #   implementation forced {scan, pallas, rotband}; "" = the rule in
+    #   consensus/star.banded_impl_effective (v1 Pallas on a TPU at
+    #   qmax <= 4096 outside a --mesh step, else the scan spec).
+    #   All three are bit-identical — a pure performance A/B knob, so it
     #   rides fingerprint._NON_SEMANTIC
     mesh_shape: Optional[tuple] = None  # (data, pass) for the batched
     #   pipeline's device mesh, e.g. (4, 2); (D,) means (D, 1); None =

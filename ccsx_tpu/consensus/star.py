@@ -56,21 +56,17 @@ def pad_to(x: np.ndarray, n: int) -> np.ndarray:
 
 
 def banded_impl() -> str:
-    """Banded DP-fill implementation choice: 'scan' (the lax.scan spec,
-    default), 'pallas' (the v1 band-local G-batched kernel,
+    """The banded DP fill asked for explicitly: 'scan' (the lax.scan
+    spec, ops/banded.py), 'pallas' (the v1 band-local G-batched kernel,
     ops/banded_pallas.py) or 'rotband' (the v2 rotating-band kernel,
-    ops/banded_rotband.py).  CCSX_BANDED_IMPL selects.
+    ops/banded_rotband.py), from CCSX_BANDED_IMPL (the CLI's
+    --banded-impl sets it); '' when none is asked for and
+    banded_impl_effective's rule chooses.
     All three are bit-identical in global+moves mode — the scan is the
     spec, both kernels are differential-tested against it
     (tests/test_banded_pallas.py three-way fuzz, interpret mode on CPU;
     chip_smoke.py checks byte-identical FASTA on the chip) — so the knob
     is non-semantic (fingerprint._NON_SEMANTIC) and free to A/B.
-
-    The scan stays the default until benchmarks/pallas_ab.py, timed on
-    the chip, names a kernel the winner; the rotband kernel is the
-    structural attack on why v1 was slower in earlier timings (the
-    ~24-op per-row select chain is replaced by residue-lane masks, ~60
-    -> ~45 tile ops/row — audit in the banded_rotband.py docstring).
     Per-dispatch attribution is visible as the ccsx_banded_impl counter
     in /metrics and the :b<impl> trace-group suffix."""
     impl = os.environ.get("CCSX_BANDED_IMPL", "")
@@ -78,39 +74,48 @@ def banded_impl() -> str:
         raise ValueError(
             f"CCSX_BANDED_IMPL={impl!r}: expected 'scan', 'pallas' or "
             "'rotband'")
-    return impl or "scan"
-
-
-def banded_impl_effective(qmax: int) -> str:
-    """The implementation _aligner actually dispatches at this qmax: the
-    kernels gate on the qmax cap and row-block alignment and fall back
-    to the scan spec (same guard for v1 and v2)."""
-    impl = banded_impl()
-    if impl != "scan" and (qmax > banded_pallas.PALLAS_MAX_QMAX
-                           or qmax % banded_pallas.ROWBLOCK != 0):
-        return "scan"
     return impl
 
 
-def use_pallas() -> bool:
-    """True iff a Pallas kernel (v1 or v2) is selected — kept for the
-    profiler/battery reports; dispatch goes through banded_impl()."""
-    return banded_impl() != "scan"
+def _backend() -> str:
+    """The backend the fill is built for (a seam for tests)."""
+    return jax.default_backend()
 
 
-@functools.lru_cache(maxsize=8)
-def _aligner(params: AlignParams):
-    # one jitted aligner per scoring config; shape specialization is
-    # handled by jit's own trace cache, so distinct (qmax, tmax) buckets
-    # reuse this callable instead of rebuilding it.  The impl choice is
-    # re-evaluated per call so CCSX_BANDED_IMPL works after first use.
+def banded_impl_effective(qmax: int, partitioned: bool = False) -> str:
+    """The fill _aligner dispatches at this qmax.
+
+    Both kernels need qmax <= PALLAS_MAX_QMAX and a multiple of ROWBLOCK;
+    outside that every choice is the scan.  An explicit banded_impl()
+    is honoured within it.  Otherwise the rule: the v1 Pallas kernel on
+    a TPU, the scan elsewhere — on the CPU (where Pallas only
+    interprets), and in a GSPMD-partitioned step (``partitioned``, set
+    by the --mesh call sites: XLA cannot partition a Mosaic call).  On a
+    v5e, N=128 fills at qmax = tmax = 4096, band 128, took 0.7733 s on
+    v1, 1.6803 s on rotband and 1.8431 s on the scan."""
+    impl = banded_impl()
+    if (qmax > banded_pallas.PALLAS_MAX_QMAX
+            or qmax % banded_pallas.ROWBLOCK != 0):
+        return "scan"
+    if impl:
+        return impl
+    return "pallas" if _backend() == "tpu" and not partitioned else "scan"
+
+
+@functools.lru_cache(maxsize=16)
+def _aligner(params: AlignParams, partitioned: bool = False):
+    # one aligner per scoring config (and partitioning); shape
+    # specialization is handled by jit's own trace cache, so distinct
+    # (qmax, tmax) buckets reuse this callable instead of rebuilding it.
+    # The fill is chosen per call (banded_impl_effective), so
+    # CCSX_BANDED_IMPL works after first use.
     # with_stats=False: the consensus rounds use only (moves, offs); the
     # slim carry drops the dead mat/aln channels from the DP scan
     scan_f = banded.make_batched("global", params, with_moves=True,
                                  with_stats=False)
 
     def f(qs, qlens, ts, tlens):
-        impl = banded_impl_effective(qs.shape[-1])
+        impl = banded_impl_effective(qs.shape[-1], partitioned)
         if impl == "scan":
             return scan_f(qs, qlens, ts, tlens)
         # with_stats=False for the kernels too: the rounds read only
@@ -119,7 +124,7 @@ def _aligner(params: AlignParams):
         mod = banded_rotband if impl == "rotband" else banded_pallas
         return mod.batched_align_global_moves(
             qs, qlens, ts, tlens, params, with_stats=False,
-            interpret=jax.default_backend() == "cpu")
+            interpret=_backend() == "cpu")
 
     return f
 

@@ -32,9 +32,9 @@ Design notes (why the kernel looks like this):
   the VMEM output block) and the final H/mat/aln bands; score extraction
   happens outside.
 
-The kernel is gated to Qmax <= PALLAS_MAX_QMAX (VMEM/SMEM budget); the
-windowed consensus path (the default) always fits.  Callers use
-ops/banded.select_aligner-style dispatch in consensus/star.py.
+The kernel is gated to Qmax <= PALLAS_MAX_QMAX (VMEM/SMEM budget) and
+Qmax % ROWBLOCK == 0; consensus/star.banded_impl_effective selects it
+(the rule is under HARDWARE STATUS below).
 
 Per-cell cost analysis (r5, after the slim with_stats=False carry):
 the per-row tile-op budget of THIS (v1, band-local) layout splits
@@ -58,21 +58,21 @@ outside the kernel, not an in-kernel post-pass.  v2's audited budget
 is ~45 ops/row vs ~60 here; the full derivation and the audit table
 live in banded_rotband.py's docstring.
 
-This v1 kernel stays as the band-local reference point of the
-promotion protocol: benchmarks/pallas_ab.py times all three arms
-(scan / v1 / v2 rotband) with the forced-execution marginal method
-and emits a machine-readable decision record {winner, margin,
-backend, method} that bench.py's vs_prev dp-kernel leg gates.  The
-scan in ops/banded.py remains the spec and the differential oracle
-for BOTH kernels; promotion (flipping the CCSX_BANDED_IMPL default
-in consensus/star.py) happens only on a hardware decision record
-that names a kernel the winner.
+The scan in ops/banded.py remains the spec and the differential
+oracle for BOTH kernels.
 
 HARDWARE STATUS: both kernels compile for a described v5e
 (tests/test_tpu_compile.py) and chip_smoke.py checks their consensus
-byte-for-byte against the scan on the chip.  No chip timing of the
-arms exists yet; until a decision record from the chip names a
-kernel the winner, the scan stays the default: it is the spec.
+byte-for-byte against the scan on the chip.  Timed on a v5e, one fill
+of N=128 alignments at qmax = tmax = 4096, band 128, with_stats=False:
+scan 1.8431 s, this v1 kernel 0.7733 s (2.4x), rotband 1.6803 s.  So
+v1 is the fill wherever nothing is forced (consensus/star.
+banded_impl_effective): on a TPU, at qmax <= PALLAS_MAX_QMAX and a
+multiple of ROWBLOCK, in every step but a GSPMD-partitioned --mesh
+one.  The scan keeps qmax > PALLAS_MAX_QMAX, the CPU and --mesh.
+At N=128, qmax = tmax = 2048 the fill takes 0.2595 s (scan 0.9255 s),
+of which the kernel calls are ~26 ms and the match tile most of the
+rest: building the tile inside the kernel is the next step.
 """
 
 from __future__ import annotations
@@ -134,13 +134,14 @@ def compute_offsets(qlen, tlen, qmax: int, band: int, maxshift: int,
 def compute_ismatch(q, t, offs, band: int, maxshift: int):
     """(Qmax, band) int8 match indicators: row i-1 lane k compares q[i-1]
     with the base entering column offs[i]+k (PAD-safe)."""
-    qmax = q.shape[0]
     tpad = jnp.concatenate([
         jnp.full((1,), PAD, jnp.uint8), t.astype(jnp.uint8),
         jnp.full((band + maxshift,), PAD, jnp.uint8),
     ])
-    j = offs[:, None] + jnp.arange(band, dtype=jnp.int32)[None, :]
-    tb = tpad[j]
+    # one band-wide window of tpad per row, as the scan's body takes it,
+    # rather than a gather per element; offs[i] <= len(t) - band + 1, so
+    # no window is clamped
+    tb = jax.vmap(lambda o: jax.lax.dynamic_slice(tpad, (o,), (band,)))(offs)
     qi = q[:, None]
     ismatch = (qi == tb) & (qi < 4) & (tb < 4)
     return ismatch.astype(jnp.int8)
@@ -148,6 +149,7 @@ def compute_ismatch(q, t, offs, band: int, maxshift: int):
 
 ROWBLOCK = 8  # rows per grid step: aligned sublane tiles for loads/stores
 GBLOCK = 8    # alignments per grid step, stacked in the sublane axis
+MAX_CALLS = 16  # kernel calls a fill is issued as (_batched_align_impl)
 
 
 # rows of the G-batched carry: H, E, [mat, aln, Emat, Ealn,] OFF
@@ -448,8 +450,10 @@ def _batched_align_impl(
     ts_f = ts.reshape(n, ts.shape[-1])
     tlens_f = tlens.reshape(n).astype(jnp.int32)
 
-    # pad the problem axis to a gblock multiple (pad rows: qlen 0, tlen 0)
-    npad = -(-n // gblock) * gblock
+    # pad the problem axis to whole kernel calls of per_call problems,
+    # whole gblock blocks each (pad rows: qlen 0, tlen 0)
+    per_call = -(-n // (gblock * MAX_CALLS)) * gblock
+    npad = -(-n // per_call) * per_call
     if npad != n:
         pad = npad - n
         qs_f = jnp.concatenate(
@@ -462,9 +466,6 @@ def _batched_align_impl(
     offs = jax.vmap(
         lambda ql, tl: compute_offsets(ql, tl, qmax, B, maxshift)
     )(qlens_f, tlens_f)
-    ismatch = jax.vmap(
-        lambda q, t, o: compute_ismatch(q, t, o, B, maxshift)
-    )(qs_f, ts_f, offs)
 
     if qmax % ROWBLOCK != 0:
         raise ValueError(f"qmax={qmax} must be a multiple of {ROWBLOCK}")
@@ -476,15 +477,22 @@ def _batched_align_impl(
     # _kernel_g docstring): bit 0 match, bits 1-3 d, bit 4 live
     aux = (((dmat & 7) << 1) | (live << 4)).astype(jnp.int8)
     lane_is0 = (jnp.arange(B, dtype=jnp.int32) == 0)[None, None, :]
-    ismatch = jnp.where(lane_is0, ismatch | aux[:, :, None], ismatch)
 
     kern = functools.partial(
         _kernel_g, qmax=qmax, band=B, maxshift=maxshift, params=params,
         with_stats=with_stats, gblock=gblock)
     nb = qmax // ROWBLOCK
-    moves, fin = pl.pallas_call(
+    # the fill is issued as up to MAX_CALLS kernel calls, one after the
+    # other, over equal runs of gblock-problem blocks (the same grid
+    # steps in the same order as one call), each call's match tile built
+    # just before it.  A 0.1 s profiler session on a v5e that falls
+    # inside one long device operation (a match tile for a whole
+    # 128-row slab took ~0.35 s) records no device event at all; here
+    # each operation covers one run of blocks.  (Kernel calls inside a
+    # lax.map loop are never recorded, so the calls are not looped.)
+    fill = pl.pallas_call(
         kern,
-        grid=(npad // gblock, nb),
+        grid=(per_call // gblock, nb),
         in_specs=[
             pl.BlockSpec((gblock, 1), lambda i, r: (i, 0),
                          memory_space=pltpu.VMEM),
@@ -498,15 +506,25 @@ def _batched_align_impl(
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((npad, qmax, B), jnp.uint8),
-            jax.ShapeDtypeStruct((npad, 8, B), jnp.int32),
+            jax.ShapeDtypeStruct((per_call, qmax, B), jnp.uint8),
+            jax.ShapeDtypeStruct((per_call, 8, B), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM(
             (_CHG if with_stats else 3, gblock, B), jnp.int32)],
         interpret=interpret,
-    )(tlens_f[:, None], ismatch)
-    moves = moves[:n]
-    fin = fin[:n]
+    )
+
+    def call(k):
+        blk = slice(k, k + per_call)
+        ismatch = jax.vmap(
+            lambda q, t, o: compute_ismatch(q, t, o, B, maxshift)
+        )(qs_f[blk], ts_f[blk], offs[blk])
+        ismatch = jnp.where(lane_is0, ismatch | aux[blk, :, None], ismatch)
+        return fill(tlens_f[blk, None], ismatch)
+
+    calls = [call(k) for k in range(0, npad, per_call)]
+    moves = jnp.concatenate([m for m, _ in calls])[:n]
+    fin = jnp.concatenate([f for _, f in calls])[:n]
     offs = offs[:n]
     qlens_f = qlens_f[:n]
     tlens_f = tlens_f[:n]

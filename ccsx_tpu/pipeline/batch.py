@@ -341,16 +341,19 @@ def _run_groups_recovering(groups, dispatch, finish, host_one, results,
 
 
 @functools.lru_cache(maxsize=128)
-def _round_body(params: AlignParams, max_ins: int, tmax: int):
+def _round_body(params: AlignParams, max_ins: int, tmax: int,
+                partitioned: bool = False):
     """The ONE star-round body both jitted steps build on: align every
     (hole, pass) window to its hole's draft (banded DP), project onto
     draft coordinates, vote per column.  _round_step and _refine_step
     share this function so the fused loop cannot drift from the
-    single-round spec the differential tests pin."""
+    single-round spec the differential tests pin.  ``partitioned``: the
+    step is GSPMD-partitioned over the --mesh, so the fill stays the
+    scan (star.banded_impl_effective)."""
     from ccsx_tpu.consensus import star as star_mod
     from ccsx_tpu.ops import msa as msa_mod
 
-    aligner = star_mod._aligner(params)  # scan default; env-gated Pallas
+    aligner = star_mod._aligner(params, partitioned)
     projector = traceback.make_projector(tmax, max_ins)
     voter = msa_mod.make_voter(max_ins)
 
@@ -380,7 +383,8 @@ def _round_body(params: AlignParams, max_ins: int, tmax: int):
 
 @functools.lru_cache(maxsize=128)
 def _round_step(params: AlignParams, max_ins: int, tmax: int,
-                bp_consts: tuple, pack: tuple | None = None):
+                bp_consts: tuple, pack: tuple | None = None,
+                partitioned: bool = False):
     """Jitted batched star round: (Z, P, qmax) passes vs (Z, tmax) drafts.
 
     Z/P/qmax shape specialization is left to jit's trace cache; tmax,
@@ -397,12 +401,12 @@ def _round_step(params: AlignParams, max_ins: int, tmax: int,
     (a fixed DMA/launch overhead per transfer), so 5 h2d + 7 d2h per
     dispatch costs ~12 latencies where 2 + 2 cost 4.  The multi-device
     path keeps separate arrays — they carry per-argument NamedShardings
-    (_shard_args)."""
+    (_shard_args); ``partitioned`` marks that GSPMD step (_round_body)."""
     import jax.numpy as jnp
 
     from ccsx_tpu.ops import breakpoint as bp_mod
 
-    body = _round_body(params, max_ins, tmax)
+    body = _round_body(params, max_ins, tmax, partitioned)
     bp_advance = bp_mod.make_bp_advance(tmax, *bp_consts)
 
     def core(qs, qlens, ts, tlens, row_mask):
@@ -506,12 +510,13 @@ def _fused_tmax(tlen: int, quant: int) -> int:
 
 @functools.lru_cache(maxsize=128)
 def _refine_step(params: AlignParams, max_ins: int, tmax: int, iters: int,
-                 bp_consts: tuple, pack: tuple | None = None):
+                 bp_consts: tuple, pack: tuple | None = None,
+                 partitioned: bool = False):
     """ONE jitted dispatch for a window's whole refinement loop.
 
     pack=(P, qmax) selects the transfer-packed single-device variant
     (same protocol and rationale as _round_step; small_out additionally
-    carries dlen and ovf).
+    carries dlen and ovf); ``partitioned`` as in _round_step.
 
     Runs `iters` speculative star rounds in a device while_loop —
     realign to draft, vote, emit insertions liberally, re-materialize
@@ -531,7 +536,7 @@ def _refine_step(params: AlignParams, max_ins: int, tmax: int, iters: int,
     from ccsx_tpu.ops import breakpoint as bp_mod
     from ccsx_tpu.ops import msa as msa_mod
 
-    one_round = _round_body(params, max_ins, tmax)
+    one_round = _round_body(params, max_ins, tmax, partitioned)
     bp_advance = bp_mod.make_bp_advance(tmax, *bp_consts)
     mat_v = jax.vmap(msa_mod.make_materializer(tmax, tmax, max_ins))
     spec_emit = jax.vmap(
@@ -669,7 +674,7 @@ def _round_body_packed(params: AlignParams, max_ins: int, tmax: int,
     from ccsx_tpu.consensus import star as star_mod
     from ccsx_tpu.ops import msa as msa_mod
 
-    aligner = star_mod._aligner(params)  # scan default; env-gated Pallas
+    aligner = star_mod._aligner(params)
     projector = traceback.make_projector(tmax, max_ins)
     voter = msa_mod.make_segment_voter(max_ins, nseg)
 
@@ -1988,7 +1993,7 @@ class BatchExecutor:
             # :b<impl> suffix + labeled counter: per-implementation
             # dispatch attribution (scan / pallas / rotband), resolved
             # at dispatch time so a compile-forced scan pin shows up
-            bimpl = banded_impl_effective(qmax)
+            bimpl = banded_impl_effective(qmax, self._mesh is not None)
             if self.metrics is not None:
                 self.metrics.bump_banded(bimpl)
             with trace.device_span(
@@ -2004,7 +2009,7 @@ class BatchExecutor:
                                        pack=(P, qmax))
                     return step(*_pack_args(args))
                 step = _round_step(cfg.align, cfg.max_ins_per_col, tmax,
-                                   self._bp_consts())
+                                   self._bp_consts(), partitioned=True)
                 return step(*self._shard_args(args, P))
 
         def finish(idxs, key, out):
@@ -2033,8 +2038,9 @@ class BatchExecutor:
                               self._round_z(len(idxs)))
         self._run_groups(
             groups, dispatch, finish, host_one, results,
-            label=lambda k: (f"round:P{k[0]}:q{k[1]}:t{k[2]}"
-                             f":b{banded_impl_effective(k[1])}"))
+            label=lambda k: (f"round:P{k[0]}:q{k[1]}:t{k[2]}:b"
+                             + banded_impl_effective(
+                                 k[1], self._mesh is not None)))
         return results
 
     def _run_refine(self, requests: List[RefineRequest]) -> List[RefineResult]:
@@ -2062,7 +2068,7 @@ class BatchExecutor:
             args = self._stack_group(requests, idxs, P, qmax, tmax)
             faultinject.fire("device_oom")
             Z = self._round_z(len(idxs))
-            bimpl = banded_impl_effective(qmax)
+            bimpl = banded_impl_effective(qmax, self._mesh is not None)
             if self.metrics is not None:
                 self.metrics.bump_banded(bimpl)
             with trace.device_span(
@@ -2080,7 +2086,8 @@ class BatchExecutor:
                                         pack=(P, qmax))
                     return step(*_pack_args(args))
                 step = _refine_step(cfg.align, cfg.max_ins_per_col, tmax,
-                                    iters, self._bp_consts())
+                                    iters, self._bp_consts(),
+                                    partitioned=True)
                 return step(*self._shard_args(args, P))
 
         def finish(idxs, key, out):
@@ -2118,8 +2125,9 @@ class BatchExecutor:
                               self._round_z(len(idxs)), iters)
         self._run_groups(
             groups, dispatch, finish, host_one, results,
-            label=lambda k: (f"refine:P{k[0]}:q{k[1]}:t{k[2]}:i{k[3]}"
-                             f":b{banded_impl_effective(k[1])}"))
+            label=lambda k: (f"refine:P{k[0]}:q{k[1]}:t{k[2]}:i{k[3]}:b"
+                             + banded_impl_effective(
+                                 k[1], self._mesh is not None)))
         return results
 
     def _run_refine_packed(

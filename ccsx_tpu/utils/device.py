@@ -26,15 +26,34 @@ def compile_cache_dir() -> str:
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE
 
 
+def stable_kernel_locations() -> None:
+    """Cut MLIR locations to their innermost frame.
+
+    A Pallas kernel reaches the program as serialized MLIR with its
+    debug locations, and the cache keys on it.  A location's traceback
+    names the callers that first traced the kernel and the jitted
+    ``jnp`` helpers it shares (a warmup thread or the dispatch thread,
+    in any order), so one program got a different key in each process; the
+    innermost frame alone, a line of the kernel, is the same in every
+    one.  (Op names keep their scopes: they come from the name stack.)
+    """
+    import jax
+
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+
+
 def enable_compile_cache() -> str:
     """Turn on the persistent XLA compilation cache; returns its directory.
 
     Batched-round shapes recur across runs, and a TPU compile costs
     seconds to minutes.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the
     directory is left to JAX; otherwise it is placed in the checkout.
+    Kernel locations are cut first (stable_kernel_locations), so that a
+    program's key is the same in every process.
     """
     import jax
 
+    stable_kernel_locations()
     cache = compile_cache_dir()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(cache, exist_ok=True)

@@ -298,7 +298,8 @@ def fasta_records(path: str, names) -> bytes:
 def cross_checks(zs, work: str, main_out: str) -> dict:
     """On the first CROSS_HOLES holes: the per-hole path (--batch off)
     with the scan, Pallas and rotband fills, each byte-identical to
-    those holes' records in the main (batched scan) run — which also
+    those holes' records in the main batched run (its fill chosen by
+    star.banded_impl_effective: v1 at qmax <= 4096) — which also
     pins that a hole's consensus does not depend on the slabs it shared.
     The kernels run in the per-hole rounds, which call the fill eagerly
     and read CCSX_BANDED_IMPL per call: the batched path would recompile
@@ -319,7 +320,7 @@ def cross_checks(zs, work: str, main_out: str) -> dict:
             check_clean(final, CROSS_HOLES, f"--batch off, {impl}")
             with open(out, "rb") as f:
                 check(f.read() == ref, f"--batch off, {impl}: FASTA "
-                      "differs from the batched scan")
+                      "differs from the batched run")
             if impl != "scan":
                 check(any(i == impl for i, _ in kernels.calls),
                       f"{impl}: the kernel never ran")

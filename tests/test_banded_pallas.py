@@ -430,3 +430,62 @@ def test_scale64_bytes_invariant_across_impls(tmp_path, monkeypatch):
         assert hashlib.md5(ref).hexdigest() == fleet_bench.SCALE64_MD5, (
             f"impl={impl}: scale64 bytes drifted "
             f"({len(ref)} bytes vs pinned {fleet_bench.SCALE64_BYTES})")
+
+
+@pytest.mark.parametrize("backend,qmax,partitioned,forced,want", [
+    ("tpu", 1536, False, "", "pallas"),
+    ("tpu", 2048, False, "", "pallas"),
+    ("tpu", banded_pallas.PALLAS_MAX_QMAX, False, "", "pallas"),
+    ("tpu", 8192, False, "", "scan"),
+    ("tpu", 2044, False, "", "scan"),        # not a ROWBLOCK multiple
+    ("cpu", 2048, False, "", "scan"),
+    ("tpu", 2048, True, "", "scan"),         # a GSPMD --mesh step
+    ("tpu", 2048, False, "scan", "scan"),
+    ("tpu", 2048, False, "rotband", "rotband"),
+    ("cpu", 2048, False, "pallas", "pallas"),
+    ("tpu", 8192, False, "pallas", "scan"),
+])
+def test_fill_selection_rule(monkeypatch, backend, qmax, partitioned,
+                             forced, want):
+    """Unforced, the v1 kernel on a TPU at a qmax it takes, outside a
+    partitioned step; the scan elsewhere.  A forced fill is honoured
+    wherever the kernels take the qmax."""
+    from ccsx_tpu.consensus import star
+
+    monkeypatch.setattr(star, "_backend", lambda: backend)
+    if forced:
+        monkeypatch.setenv("CCSX_BANDED_IMPL", forced)
+    else:
+        monkeypatch.delenv("CCSX_BANDED_IMPL", raising=False)
+    assert star.banded_impl_effective(qmax, partitioned) == want
+
+
+def test_fill_selection_rejects_unknown_impl(monkeypatch):
+    from ccsx_tpu.consensus import star
+
+    monkeypatch.setenv("CCSX_BANDED_IMPL", "fast")
+    with pytest.raises(ValueError):
+        star.banded_impl_effective(2048)
+
+
+@pytest.mark.parametrize("n,calls,grid", [
+    (8, 1, 1),        # one gblock block
+    (24, 3, 1),       # a call per block
+    (128, 16, 1),     # a 128-row slab
+    (260, 11, 3),     # at most MAX_CALLS calls of 3 blocks each
+])
+def test_fill_is_issued_per_block(n, calls, grid):
+    """The fill runs as at most MAX_CALLS kernel calls over equal runs of
+    whole GBLOCK blocks, each with its own match tile, so that no single
+    device operation spans a whole slab."""
+    assert banded_pallas.MAX_CALLS == 16
+    qmax = 64
+    S = jax.ShapeDtypeStruct
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: banded_pallas.batched_align_global_moves(
+            *a, AlignParams(), with_stats=False, interpret=True))(
+        S((n, qmax), jnp.uint8), S((n,), jnp.int32),
+        S((n, qmax), jnp.uint8), S((n,), jnp.int32)))
+    assert jaxpr.count("pallas_call[") == calls
+    assert jaxpr.count(f"grid=({grid}, {qmax // banded_pallas.ROWBLOCK})") \
+        == calls

@@ -393,3 +393,36 @@ def test_bucketed_steps_name_their_program_and_stages(refine, pack):
     assert f"module @jit_{name} " in text
     for stage in trace.STAGES:
         assert f"/{stage}/" in text, stage
+
+
+@pytest.mark.parametrize("partitioned", [False, True])
+@pytest.mark.parametrize("refine", [False, True])
+def test_mesh_steps_keep_the_scan_on_tpu(monkeypatch, refine, partitioned):
+    """Traced as for a TPU: the bucketed steps take the v1 kernel, but
+    not when the --mesh call sites mark them GSPMD-partitioned (XLA
+    cannot partition a Mosaic call)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ccsx_tpu.consensus import star
+    from ccsx_tpu.pipeline import batch as bm
+
+    monkeypatch.setattr(star, "_backend", lambda: "tpu")
+    monkeypatch.delenv("CCSX_BANDED_IMPL", raising=False)
+    cfg = CcsConfig(is_bam=False)
+    Z, P, qmax, tmax = 2, 4, 128, 256
+    bp = BatchExecutor(cfg)._bp_consts()
+    # unwrapped: a fresh jit, so no trace cached under the CPU's choice
+    if refine:
+        step = bm._refine_step.__wrapped__(
+            cfg.align, cfg.max_ins_per_col, tmax, 2, bp,
+            partitioned=partitioned)
+    else:
+        step = bm._round_step.__wrapped__(
+            cfg.align, cfg.max_ins_per_col, tmax, bp,
+            partitioned=partitioned)
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(step)(
+        S((Z, P, qmax), jnp.uint8), S((Z, P), jnp.int32),
+        S((Z, tmax), jnp.uint8), S((Z,), jnp.int32), S((Z, P), jnp.bool_))
+    assert ("pallas_call" in str(jaxpr)) is not partitioned

@@ -262,10 +262,14 @@ def test_resolve_device_auto_starts_no_subprocess(monkeypatch):
     assert device.resolve_device("auto") == "cpu"
 
 
+LOCATIONS = ("jax_traceback_in_locations_limit", 1)
+
+
 @pytest.mark.parametrize("env_dir", [True, False])
 def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
     """JAX_COMPILATION_CACHE_DIR is honoured (and left to JAX: nothing
-    is set in code); unset, the cache is <checkout>/.jax_cache."""
+    is set in code); unset, the cache is <checkout>/.jax_cache.  Either
+    way, locations keep one frame (stable keys for kernels)."""
     import jax
 
     from ccsx_tpu.utils import device
@@ -276,12 +280,12 @@ def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
     if env_dir:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert device.enable_compile_cache() == str(tmp_path)
-        assert updates == []
+        assert updates == [LOCATIONS]
     else:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         want = os.path.join(_REPO, ".jax_cache")
         assert device.enable_compile_cache() == want
-        assert updates == [("jax_compilation_cache_dir", want)]
+        assert updates == [LOCATIONS, ("jax_compilation_cache_dir", want)]
 
 
 # ---------- journal v2: crash-safe resume ----------

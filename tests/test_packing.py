@@ -207,3 +207,55 @@ def test_packed_refine_step_names_its_program_and_stages(fused):
     for stage in trace.STAGES:
         # a part of an op-name path; 'vmap(<scope>)' under the fused vmap
         assert re.search(rf"[/(]{stage}[)/]", text), stage
+
+
+
+@pytest.fixture
+def clear_packed_steps():
+    """Drops the jitted packed steps, and again after the test: a fill
+    forced by the environment is chosen when a step is first traced."""
+    from ccsx_tpu.pipeline import batch as bm
+
+    def clear():
+        bm._refine_step_packed.cache_clear()
+        bm._refine_step_packed_fused.cache_clear()
+
+    clear()
+    yield clear
+    clear()
+
+
+@pytest.mark.parametrize("one_device", [True, False])
+def test_packed_refine_pallas_matches_scan(rng, monkeypatch,
+                                           clear_packed_steps, one_device):
+    """The v1 kernel (interpret mode here) in the packed refine step, on
+    one device and in the fused multi-chip step, gives the scan's
+    results byte for byte."""
+    import jax
+
+    from ccsx_tpu.ops import banded_pallas
+
+    kernel = banded_pallas.batched_align_global_moves
+    traced = []
+    monkeypatch.setattr(banded_pallas, "batched_align_global_moves",
+                        lambda *a, **k: traced.append(1) or kernel(*a, **k))
+    cfg = CcsConfig(is_bam=False, slab_rows=16)
+    _, reqs = _requests(rng, cfg)
+    devices = jax.devices()[:1] if one_device else None
+    out = {}
+    for impl in ("scan", "pallas"):
+        monkeypatch.setenv("CCSX_BANDED_IMPL", impl)
+        clear_packed_steps()
+        metrics = Metrics()
+        out[impl] = BatchExecutor(cfg, metrics=metrics,
+                                  devices=devices).run(reqs)
+        assert set(metrics.banded_dispatches) == {impl}
+        assert metrics.host_fallbacks == 0
+        assert bool(traced) == (impl == "pallas")
+    for a, b in zip(out["scan"], out["pallas"]):
+        assert a.rr.tlen == b.rr.tlen and a.rr.bp == b.rr.bp
+        for f in ("cons", "ins_base", "ins_votes", "ncov", "nwin",
+                  "advance"):
+            np.testing.assert_array_equal(getattr(a.rr, f),
+                                          getattr(b.rr, f), err_msg=f)
+        np.testing.assert_array_equal(a.draft, b.draft)

@@ -10,6 +10,7 @@ file does.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,68 @@ def test_packed_refine_step_fits_one_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES
+
+
+def test_packed_refine_step_takes_the_kernel_on_v5e(one_chip, monkeypatch):
+    """The packed refine step at a whole-read amplicon shape (128-row
+    slab, qmax 2048, the fused draft capacity above it) with nothing
+    forced: traced for a TPU it selects the v1 kernel, which Mosaic
+    compiles into the program, and the program fits one chip."""
+    from ccsx_tpu.consensus import star
+    from ccsx_tpu.pipeline import batch
+
+    monkeypatch.setattr(star, "_backend", lambda: "tpu")
+    monkeypatch.delenv("CCSX_BANDED_IMPL", raising=False)
+    cfg = CcsConfig()
+    R = cfg.slab_rows
+    H = R // 4                                        # pack.SEG_DIV
+    qmax = 2048
+    tmax = batch._fused_tmax(qmax, cfg.len_bucket_quant)
+    assert star.banded_impl_effective(qmax) == "pallas"
+    bp = (cfg.bp_window, cfg.bp_minwin, cfg.bp_rowrate, cfg.bp_colrate,
+          cfg.bp_colrate_lowpass)
+    # unwrapped: a fresh jit, so no trace cached under the CPU's choice
+    step = batch._refine_step_packed.__wrapped__(
+        cfg.align, cfg.max_ins_per_col, tmax, cfg.refine_iters, H, bp,
+        (R, qmax))
+    lbig, lsmall = batch._slab_wire_sizes(R, qmax, H, tmax,
+                                          cfg.max_ins_per_col)
+    compiled = step.lower(_spec((lbig,), jnp.uint8, one_chip),
+                          _spec((lsmall,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    print(f"packed refine (v1 fill) R={R} qmax={qmax} tmax={tmax}: {mem}")
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES
+
+
+def _kernel_bodies(text):
+    return re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+
+
+def test_kernel_body_does_not_name_its_callers(one_chip):
+    """At the one-frame location limit that enable_compile_cache sets,
+    the v1 kernel's serialized body, on which the persistent compile
+    cache keys, is the same whichever call stack traced it."""
+    from ccsx_tpu.utils import device
+
+    def first_caller(n):
+        return _fill("pallas").lower(
+            _spec((n, 256), jnp.uint8, one_chip),
+            _spec((n,), jnp.int32, one_chip),
+            _spec((n, 256), jnp.uint8, one_chip),
+            _spec((n,), jnp.int32, one_chip)).as_text()
+
+    def second_caller(n):
+        return first_caller(n)
+
+    was = jax.config.jax_traceback_in_locations_limit
+    try:
+        device.stable_kernel_locations()
+        a = _kernel_bodies(first_caller(8))
+        b = _kernel_bodies(second_caller(16))
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+    # one body per kernel call: one call for 8 problems, two for 16
+    assert len(a) == 1 and set(b) == set(a)
