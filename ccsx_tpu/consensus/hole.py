@@ -10,7 +10,7 @@ from typing import Optional
 
 from ccsx_tpu.config import CcsConfig
 from ccsx_tpu.consensus import prepare as prep
-from ccsx_tpu.consensus.star import StarMsa, run_rounds
+from ccsx_tpu.consensus.star import StarMsa, run_rounds, window_counts
 from ccsx_tpu.consensus.windowed import windowed_gen
 from ccsx_tpu.ops import encode as enc
 
@@ -70,11 +70,12 @@ def full_gen_for_zmw(zmw, cfg: CcsConfig):
 
 def _counted(gen, stats: dict):
     """Count the generator's device requests (one RefineRequest per
-    window attempt) into stats['windows']."""
+    window attempt) into stats, as star.window_counts does."""
     try:
         req = next(gen)
         while True:
-            stats["windows"] = stats.get("windows", 0) + 1
+            for k, n in window_counts([req]).items():
+                stats[k] = stats.get(k, 0) + n
             rr = yield req
             req = gen.send(rr)
     except StopIteration as e:
@@ -87,9 +88,9 @@ def ccs_hole(zmw, aligner, cfg: CcsConfig,
     Returns (seq_bytes, qual_bytes|None) per encode.to_record, or None
     for a skipped hole.
 
-    stats, if given, receives per-hole counters ('windows': window
-    refinements run) so the driver can aggregate them thread-safely on
-    its own side.
+    stats, if given, receives per-hole counters (star.window_counts:
+    window refinements run, growths, forced flushes) so the driver can
+    aggregate them thread-safely on its own side.
     """
     gen = consensus_gen_for_zmw(zmw, aligner, cfg)
     if gen is None:

@@ -179,6 +179,20 @@ class RefineRequest:
     row_mask: np.ndarray  # (P,) bool
     draft: np.ndarray     # (tlen,) uint8 codes — initial alignment target
     iters: int            # speculative refinement rounds before the final
+    # why the windowed loop asks for this window (windowed_gen): "growth"
+    # — the hole's previous attempt found no breakpoint and this is it
+    # grown by window_add; "forced_flush" — the previous window found
+    # none at max_window and was flushed; "" otherwise
+    after: str = ""
+
+
+def window_counts(requests) -> dict:
+    """The windowed loop's counts over a batch of RefineRequests: window
+    attempts, growths and forced flushes (the Metrics counters)."""
+    after = [r.after for r in requests]
+    return {"windows": len(after),
+            "window_growths": after.count("growth"),
+            "window_forced_flushes": after.count("forced_flush")}
 
 
 @dataclasses.dataclass
@@ -243,12 +257,13 @@ def refine_host(round_fn, qs, qlens, row_mask, draft, iters: int) -> "RefineResu
     return RefineResult(rr=rr)
 
 
-def refine_rounds_gen(qs, qlens, row_mask, draft, iters: int):
+def refine_rounds_gen(qs, qlens, row_mask, draft, iters: int,
+                      after: str = ""):
     """Request one window's refinement from the driving executor; returns
     the RefineResult (final round + lazy strict draft), whichever
     executor (per-hole host loop or fused batched device step)
     satisfies it."""
-    res = yield RefineRequest(qs, qlens, row_mask, draft, iters)
+    res = yield RefineRequest(qs, qlens, row_mask, draft, iters, after)
     return res
 
 

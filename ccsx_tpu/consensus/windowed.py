@@ -25,7 +25,7 @@ keep exactly that structure — it is what makes the kernel shapes static:
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -83,11 +83,30 @@ def _advance(rr: RoundResult, bp: int) -> np.ndarray:
     return (nongap + ins + rr.lead_ins).astype(np.int64)
 
 
+def final_window_lengths(cfg: CcsConfig,
+                         qlens) -> Optional[Tuple[int, int]]:
+    """The range of a hole's final window's longest pass, predicted from
+    one of its earlier windows' pass lengths ``qlens``, or None when no
+    final window follows it.  Only a window at the first width (every
+    pass cut at window_init) has one after it.  The loop flushes the
+    tails once the shortest has under window_init + window_minlen bases
+    left (the fits rule in windowed_gen), each holds over window_minlen
+    once the window before it advanced, and the longest runs a few
+    bases past the shortest: (window_minlen + 1, window_init +
+    window_minlen + 1)."""
+    if not cfg.split_subread or not (np.asarray(qlens)
+                                     == cfg.window_init).all():
+        return None
+    return (cfg.window_minlen + 1,
+            cfg.window_init + cfg.window_minlen + 1)
+
+
 def windowed_gen(passes: List[np.ndarray], cfg: CcsConfig):
     """Generator form of consensus_windowed: yields one RefineRequest per
     window attempt, receives RefineResults, returns the consensus codes
     (or (codes, phred_quals) with cfg.emit_quality) via
-    StopIteration.value."""
+    StopIteration.value.  A request after an attempt that found no
+    breakpoint says so in its ``after`` ("growth", "forced_flush")."""
     sm = StarMsa(cfg.align, cfg.max_ins_per_col, cfg.len_bucket_quant)
     if len(passes) > cfg.max_passes:
         passes = passes[: cfg.max_passes]
@@ -107,6 +126,7 @@ def windowed_gen(passes: List[np.ndarray], cfg: CcsConfig):
         out.append(c)
         outq.append(q)
 
+    after = ""
     flag = True
     while flag:
         window_size = cfg.window_init
@@ -125,8 +145,9 @@ def windowed_gen(passes: List[np.ndarray], cfg: CcsConfig):
             # consume only rr (materialize(upto=bp) + advance), the
             # final flush materializes the strict draft
             res = yield from refine_rounds_gen(
-                qs, qlens, row_mask, windows[0], cfg.refine_iters)
+                qs, qlens, row_mask, windows[0], cfg.refine_iters, after)
             rr = res.rr
+            after = ""
 
             if final:
                 # the strict materialization of the final round — emit()
@@ -154,12 +175,14 @@ def windowed_gen(passes: List[np.ndarray], cfg: CcsConfig):
                 # mode this is unbounded like the reference — the fits
                 # check above flushes the tails once the window spans the
                 # remaining pass lengths, exactly as main.c:555-564 does
+                after = "growth"
                 window_size += cfg.window_add
                 continue
             if bp is None:
                 # growth cap reached: force a flush point (delta vs the
                 # reference's unbounded growth; disable via
                 # window_growth="grow")
+                after = "forced_flush"
                 bp = max(rr.tlen - cfg.bp_window, 1)
             emit(rr, upto=bp)
             if rr.advance is not None:

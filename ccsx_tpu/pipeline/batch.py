@@ -46,11 +46,12 @@ import numpy as np
 
 from ccsx_tpu.config import AlignParams, CcsConfig
 from ccsx_tpu.consensus import prepare as prep_mod
+from ccsx_tpu.consensus import windowed
 from ccsx_tpu.consensus.align_host import MatchResult
 from ccsx_tpu.consensus.hole import full_gen_for_zmw
 from ccsx_tpu.consensus.star import (
     RefineRequest, RefineResult, RoundRequest, RoundResult, StarMsa,
-    banded_impl_effective, bucket_len, pad_to, refine_host,
+    banded_impl_effective, bucket_len, pad_to, refine_host, window_counts,
 )
 from ccsx_tpu.ops import banded
 from ccsx_tpu.ops import encode as enc
@@ -1547,6 +1548,7 @@ class BatchExecutor:
         # with each hole counted once per group (_group_holes)
         self._group_pred: Dict[tuple, tuple] = {}
         self._group_holes: Dict[tuple, set] = {}
+        self._tails_warmed = False     # _warm_tail_groups ran
         n_dev = len(self._devices)
         # ragged pass-packing (pipeline/pack.py) replaces the per-P
         # shape grouping for the production RefineRequest path, and
@@ -1827,6 +1829,37 @@ class BatchExecutor:
                         functools.partial(self._warm_build, qmax, tmax,
                                           req.iters, h, hH, dstack))
         self._group_pred[gk] = (acc, R, key)
+        if not self._tails_warmed:
+            tails = windowed.final_window_lengths(
+                self.cfg, req.qlens[req.row_mask])
+            if tails is not None:
+                self._tails_warmed = True
+                self._warm_tail_groups(tails, req.iters, dstack)
+
+    def _warm_tail_groups(self, tails, iters: int, dstack: int) -> None:
+        """Warm the groups of windowed holes' final windows, once, from
+        the range of their longest pass (windowed.final_window_lengths):
+        each length bucket in it, with the draft in that bucket or the
+        one below (tmax one bucket above the draft's).  These groups
+        appear only when holes reach their ends and are dispatched in
+        the sweep right after, too late for the warm a request's own
+        group gets.  Each is warmed at every canonical height: most
+        sweeps spread a few finishing holes over them, but the cohort
+        that the admission ramp starts together ends together and fills
+        some past the lower height (a program loaded then, in a warm
+        run, lands between two bursts of records)."""
+        q = self.len_quant
+        qmax, top = (bucket_len(n, q) for n in tails)
+        while qmax <= top:
+            for tmax in (qmax, bucket_len(qmax + 1, q)):
+                for R in pack_mod.canonical_heights(self.slab_rows,
+                                                    self.slab_ladder):
+                    H = max(1, R // pack_mod.SEG_DIV)
+                    self._warmup.submit(
+                        self._warm_key(qmax, tmax, iters, R, dstack),
+                        functools.partial(self._warm_build, qmax, tmax,
+                                          iters, R, H, dstack))
+            qmax = bucket_len(qmax + 1, q)
 
     def _warm_sweep_shapes(self, shapes) -> None:
         """Sweep-time exact warming: by group-construction time the
@@ -2060,8 +2093,8 @@ class BatchExecutor:
 
         results: List[Optional[RefineResult]] = [None] * len(requests)
         if self.metrics is not None:
-            self.metrics.bump(windows=len(requests),
-                              device_dispatches=len(groups))
+            self.metrics.bump(device_dispatches=len(groups),
+                              **window_counts(requests))
 
         def dispatch(idxs, key):
             P, qmax, tmax, iters = key
@@ -2145,7 +2178,7 @@ class BatchExecutor:
         nrows = [int(r.row_mask.sum()) for r in requests]
         results: List[Optional[RefineResult]] = [None] * len(requests)
         if self.metrics is not None:
-            self.metrics.bump(windows=len(requests))
+            self.metrics.bump(**window_counts(requests))
 
         def host_one(i):
             req = requests[i]
@@ -2642,6 +2675,7 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
             pool = PrepPool(stream, cfg, pair_executor, metrics,
                             threads=n_prep, max_outstanding=4 * cap,
                             resume=resume)
+        ramped = explicit_window    # a sweep has held a full cap
         while True:
             admitted_full = False
             # the pool poll (or inline ingest + prep) and admission
@@ -2699,7 +2733,15 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                         admit(h)
                     admitted_full = len(active) >= window
             emit_ready()
-            if not active:
+            # until a sweep has held a full cap, each sweep waits for its
+            # window to fill (or the input to end): with holes handed
+            # over in input order, the ramp's sweeps then hold the same
+            # holes on every run, whatever the pace of prep, and so do
+            # the sweeps after it (whose holes take the slots the ramp's
+            # free), so the records reach the writer in the same bursts
+            short = (pool is not None and not ramped
+                     and len(active) < window and not pool.drained())
+            if not active or short:
                 if pool is None:
                     if exhausted:
                         break
@@ -2712,7 +2754,7 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                 # first hole the instant it appears would fragment the
                 # sweep into near-empty slabs and per-hole dispatches);
                 # the moment prep pauses with work in hand — or the
-                # window fills — sweep what we have.
+                # window fills — sweep what we have (past the ramp).
                 with trace.span("admit", cat="host"):
                     while len(active) < window and not pool.drained():
                         if adm is not None and not adm.try_acquire():
@@ -2738,7 +2780,7 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                         if h is None:
                             if adm is not None:
                                 adm.release()
-                            if active:
+                            if active and ramped:
                                 break
                             metrics.heartbeat()
                             continue
@@ -2748,6 +2790,7 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                 metrics.heartbeat()
                 if not active:
                     continue
+            ramped = ramped or len(active) >= cap
             # one batched sweep over every pending request, split by
             # kind: prep pair alignments (strand_match walks) and
             # consensus rounds each batch across holes
@@ -2774,8 +2817,12 @@ def drive_batched(stream, writer, cfg: CcsConfig, journal: Journal,
                         trace.span("refine_sweep", cat="compute",
                                    n=len(round_holes)):
                     rres = executor.run([h.req for h in round_holes])
-                    for h, rr in zip(round_holes, rres):
-                        _feed_hole(h, rr)
+                    # route the results back into the holes' generators,
+                    # which yield their next windows' requests
+                    with trace.span("window_next", cat="host",
+                                    n=len(round_holes)):
+                        for h, rr in zip(round_holes, rres):
+                            _feed_hole(h, rr)
             still: List[_Hole] = []
             for h in active:
                 if h.done:

@@ -12,7 +12,9 @@ overlap for the batched scheduler:
   input stream ahead of the admission window, run each hole's combined
   prep generator (encode + group_lens + the orientation/strand walk,
   consensus/prepare.py) to its FIRST consensus request, and publish the
-  prepped hole on a thread-safe ready queue.  The driver's sweep loop
+  prepped hole on a thread-safe ready queue, which hands holes to the
+  driver in input order (so which holes share a sweep does not depend
+  on which worker finished first).  The driver's sweep loop
   keeps dispatching device work the whole time; it only blocks on the
   queue when it has nothing dispatchable (that wait is
   ``Metrics.t_prep_blocked`` — the critical-path prep exposure the
@@ -54,7 +56,7 @@ import os
 import sys
 import threading
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 from ccsx_tpu.consensus import prepare as prep_mod
 from ccsx_tpu.utils import faultinject
@@ -167,7 +169,8 @@ class PrepPool:
         self._finish = finish or batch_mod._finish
         self._gate = _PairGate(pair_executor, metrics)
         self._cv = threading.Condition()
-        self._ready: List[object] = []
+        self._ready: Dict[int, object] = {}   # prepped holes by index
+        self._next_take = 0          # the index the driver takes next
         self._budget = threading.Semaphore(max(1, int(max_outstanding)))
         self._ingest_lock = threading.Lock()
         self._next_idx = 0
@@ -296,7 +299,7 @@ class PrepPool:
 
     def _publish(self, h) -> None:
         with self._cv:
-            self._ready.append(h)
+            self._ready[h.idx] = h
             d = len(self._ready)
             self._metrics.prep_queue_depth = d
             if d > self._metrics.prep_queue_peak:
@@ -310,28 +313,33 @@ class PrepPool:
             e, self._ingest_error = self._ingest_error, None
             raise e
 
+    def _can_take(self) -> bool:
+        return self._next_take in self._ready
+
     def _take_locked(self):
-        h = self._ready.pop(0)
+        h = self._ready.pop(self._next_take)
+        self._next_take += 1
         self._outstanding -= 1   # the driver owns it from here
         self._metrics.prep_queue_depth = len(self._ready)
         return h
 
     def poll(self):
-        """Next prepped hole without blocking, or None."""
+        """The next hole in input order if it is prepped, else None,
+        without blocking."""
         with self._cv:
-            if self._ready:
+            if self._can_take():
                 return self._take_locked()
         self._raise_ingest_error()
         return None
 
     def get(self, timeout: float = 1.0):
-        """Next prepped hole, blocking up to ``timeout`` — the driver's
-        nothing-dispatchable wait (timed by the caller into
-        t_prep_blocked)."""
+        """The next hole in input order, blocking up to ``timeout`` for
+        its prep — the driver's nothing-dispatchable wait (timed by the
+        caller into t_prep_blocked)."""
         with self._cv:
             self._cv.wait_for(
-                lambda: self._ready or self.drained(), timeout=timeout)
-            if self._ready:
+                lambda: self._can_take() or self.drained(), timeout=timeout)
+            if self._can_take():
                 return self._take_locked()
         self._raise_ingest_error()
         return None

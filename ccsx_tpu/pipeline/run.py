@@ -313,6 +313,9 @@ def run_pipeline(in_path: str, out_path: str, cfg: CcsConfig,
         # projector, voter); the batched executor's count is exact (one
         # fused dispatch per shape group)
         metrics.windows += stats.get("windows", 0)
+        metrics.bump(window_growths=stats.get("window_growths", 0),
+                     window_forced_flushes=stats.get(
+                         "window_forced_flushes", 0))
         metrics.device_dispatches += 3 * stats.get("windows", 0)
         wrote = False
         with metrics.timer("write"), \

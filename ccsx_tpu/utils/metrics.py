@@ -250,6 +250,10 @@ class Metrics:
     holes_corrupt: int = 0
     corrupt_reasons: dict = dataclasses.field(default_factory=dict)
     windows: int = 0
+    # window attempts of the windowed loop (consensus/windowed.py) that
+    # found no breakpoint: grown by window_add, or flushed at max_window
+    window_growths: int = 0
+    window_forced_flushes: int = 0
     pair_alignments: int = 0   # batched prep strand_match pairs
     # pre-alignment plane (ISSUE 11, ops/sketch.py + ops/seed_device.py):
     # candidate pairs scored by the batched device screen, pairs it
@@ -643,6 +647,8 @@ class Metrics:
             "holes_corrupt": self.holes_corrupt,
             "stalls": self.stalls,
             "windows": self.windows,
+            "window_growths": self.window_growths,
+            "window_forced_flushes": self.window_forced_flushes,
             "pair_alignments": self.pair_alignments,
             "pairs_screened": self.pairs_screened,
             "pairs_prefiltered": self.pairs_prefiltered,

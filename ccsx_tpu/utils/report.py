@@ -69,7 +69,8 @@ REPORT_TILE_KEYS = (
 REPORT_HEADER_KEYS = (
     "holes_in", "holes_out", "holes_failed", "holes_filtered",
     "holes_corrupt",
-    "windows", "device_dispatches", "oom_resplits", "host_fallbacks",
+    "windows", "window_growths", "window_forced_flushes",
+    "device_dispatches", "oom_resplits", "host_fallbacks",
     "device_hangs", "breaker_trips", "breaker_state",
     "stalls", "elapsed_s", "ingest_bytes",
 )

@@ -60,7 +60,8 @@ PORT_TRIES = 32
 PROM_COUNTERS = (
     "holes_in", "holes_out", "holes_failed", "holes_filtered",
     "holes_corrupt", "stalls",
-    "windows", "pair_alignments", "device_dispatches", "refine_overflows",
+    "windows", "window_growths", "window_forced_flushes",
+    "pair_alignments", "device_dispatches", "refine_overflows",
     # pre-alignment plane (ops/sketch.py + ops/seed_device.py): screen
     # coverage/rejections and the device-vs-host seeding split
     "pairs_screened", "pairs_prefiltered",

@@ -148,6 +148,56 @@ def test_window_growth_schedule():
     assert _grow_window(16384, 16384, 4) == 16384
 
 
+def test_ramp_sweeps_hold_the_same_holes_whatever_prep_pace(monkeypatch):
+    """While the adaptive window grows, each sweep waits for its window
+    and the pool hands holes over in input order: the ramp's sweeps are
+    holes [0], [1..4], [5..20] for a cap of 16, however unevenly prep
+    finishes them (a hole's prep here sleeps 0-90 ms by its index)."""
+    import time
+    import types
+
+    from ccsx_tpu.consensus import hole as hole_mod
+    from ccsx_tpu.pipeline import batch
+    from ccsx_tpu.utils.journal import Journal
+    from ccsx_tpu.utils.metrics import Metrics
+
+    def gen(z, cfg):
+        time.sleep(0.03 * (3 - int(z.hole) % 4))
+        yield ("window", int(z.hole))
+        return np.zeros(8, np.uint8)
+
+    sweeps = []
+
+    def run(self, requests):
+        sweeps.append(sorted(r[1] for r in requests))
+        return [None] * len(requests)
+
+    monkeypatch.setattr(hole_mod, "full_gen_for_zmw", gen)
+    monkeypatch.setattr(batch.BatchExecutor, "run", run)
+
+    class Writer:
+        names = []
+
+        def put(self, name, seq, qual=None):
+            self.names.append(name)
+
+        def flush(self):
+            pass
+
+        def close(self):
+            pass
+
+    cfg = CcsConfig(is_bam=False, zmw_microbatch=16, prep_threads=4)
+    zs = [types.SimpleNamespace(movie="mv", hole=str(i)) for i in range(24)]
+    w = Writer()
+    assert batch.drive_batched(iter(zs), w, cfg,
+                               Journal.for_run(None, "in", cfg),
+                               Metrics(verbose=0, stream=None)) == 0
+    assert sweeps[:3] == [[0], [1, 2, 3, 4], list(range(5, 21))]
+    assert sorted(h for s in sweeps for h in s) == list(range(24))
+    assert w.names == [f"mv/{i}/ccs" for i in range(24)]
+
+
 def test_resolve_prep_threads():
     assert resolve_prep_threads(CcsConfig(prep_threads=0)) == 0
     assert resolve_prep_threads(CcsConfig(prep_threads=7)) == 7
