@@ -14,13 +14,17 @@ Design notes (why the kernel looks like this):
 * The band-offset schedule ``offs`` is data-INdependent — it is a pure
   function of (qlen, tlen, line) — so it is computed outside the kernel
   with a tiny vectorized ``lax.scan`` (compute_offsets) and fed to the
-  kernel through SMEM.  The traceback needs the same array, so nothing is
-  wasted.
+  kernel as each row's shift d.  The traceback needs the same array, so
+  nothing is wasted.
 * The only per-cell input the recurrence needs from (q, t) is the match
-  indicator; ``ismatch[i-1, k] = q[i-1] == t[offs[i]+k-1]`` is precomputed
-  as a (Qmax, B) int8 gather outside the kernel.  Inside, each row is a
-  dynamic *sublane* read — cheap — whereas gathering t by a dynamic lane
-  offset in-kernel would be a lane-rotate per row.
+  indicator ``q[i-1] == t[offs[i]+k-1]``, and the kernel builds it
+  itself.  Each problem's padded template enters as one VMEM block per
+  problem block; a 2B-lane window of it, from the band's offset, is kept
+  in scratch and rebuilt every few grid steps (a select over 128-lane
+  blocks, then a lane gather at the offset's remainder), and each row's
+  template band is one per-problem lane gather of that window.  Outside
+  the kernel there is no per-row gather or loop: q[i-1]'s code rides
+  with the row's other packed scalars.
 * The previous-row band must be shifted by d = offs[i] - offs[i-1] ∈
   [0, maxshift].  d is tiny, so the kernel computes all maxshift+2 static
   lane shifts of the carry block and picks with a select chain — static
@@ -44,7 +48,9 @@ roll+cmp+select), ~15 ops recurrence+moves, ~60 total.  The select
 chain is irreducible in the band-local lane layout: d differs per
 problem inside a G-block, so a scalar dynamic rotate cannot replace
 the per-candidate static shifts, and pre-shifting the carry at row
-end just moves the same chain.
+end just moves the same chain.  Building the match in the kernel adds
+~8 ops a row (two lane gathers of the template window, a select, a
+compare) and the window's rebuild every few grid steps.
 
 The structural attack — a rotating-band layout where lane k holds
 column j ≡ k mod B, so the chain becomes one per-problem mask +
@@ -64,15 +70,19 @@ oracle for BOTH kernels.
 HARDWARE STATUS: both kernels compile for a described v5e
 (tests/test_tpu_compile.py) and chip_smoke.py checks their consensus
 byte-for-byte against the scan on the chip.  Timed on a v5e, one fill
-of N=128 alignments at qmax = tmax = 4096, band 128, with_stats=False:
-scan 1.8431 s, this v1 kernel 0.7733 s (2.4x), rotband 1.6803 s.  So
-v1 is the fill wherever nothing is forced (consensus/star.
-banded_impl_effective): on a TPU, at qmax <= PALLAS_MAX_QMAX and a
-multiple of ROWBLOCK, in every step but a GSPMD-partitioned --mesh
-one.  The scan keeps qmax > PALLAS_MAX_QMAX, the CPU and --mesh.
-At N=128, qmax = tmax = 2048 the fill takes 0.2595 s (scan 0.9255 s),
-of which the kernel calls are ~26 ms and the match tile most of the
-rest: building the tile inside the kernel is the next step.
+of N=128 alignments at qmax = tmax = 4096, band 128, with_stats=False,
+with the match tile still built outside the kernel: scan 1.8431 s,
+this v1 kernel 0.7733 s, rotband 1.6803 s.  So v1 is the fill
+wherever nothing is forced (consensus/star.banded_impl_effective): on
+a TPU, at qmax <= PALLAS_MAX_QMAX and a multiple of ROWBLOCK, in every
+step but a GSPMD-partitioned --mesh one.  The scan keeps qmax >
+PALLAS_MAX_QMAX, the CPU and --mesh.
+With the match indicators built in the kernel, the same fill on a v5e
+(median of 10, moves/offs/score identical to the scan's and to the
+out-of-kernel tile's): at qmax = tmax = 2048, 0.0321 s (0.2614 s with
+the tile outside), of which the 16 kernel calls are 25.5 ms (1.59 ms
+each, as before), compute_offsets' scan 4.8 ms and the packing ~2 ms;
+at 4096, 0.0626 s (0.5204 s), kernel calls 51.0 ms, the scan 9.6 ms.
 """
 
 from __future__ import annotations
@@ -131,33 +141,18 @@ def compute_offsets(qlen, tlen, qmax: int, band: int, maxshift: int,
     return offs
 
 
-def compute_ismatch(q, t, offs, band: int, maxshift: int):
-    """(Qmax, band) int8 match indicators: row i-1 lane k compares q[i-1]
-    with the base entering column offs[i]+k (PAD-safe)."""
-    tpad = jnp.concatenate([
-        jnp.full((1,), PAD, jnp.uint8), t.astype(jnp.uint8),
-        jnp.full((band + maxshift,), PAD, jnp.uint8),
-    ])
-    # one band-wide window of tpad per row, as the scan's body takes it,
-    # rather than a gather per element; offs[i] <= len(t) - band + 1, so
-    # no window is clamped
-    tb = jax.vmap(lambda o: jax.lax.dynamic_slice(tpad, (o,), (band,)))(offs)
-    qi = q[:, None]
-    ismatch = (qi == tb) & (qi < 4) & (tb < 4)
-    return ismatch.astype(jnp.int8)
-
-
 ROWBLOCK = 8  # rows per grid step: aligned sublane tiles for loads/stores
 GBLOCK = 8    # alignments per grid step, stacked in the sublane axis
 MAX_CALLS = 16  # kernel calls a fill is issued as (_batched_align_impl)
+NOBASE_Q = 4  # packed query code of a non-base; templates' non-bases are PAD
 
 
 # rows of the G-batched carry: H, E, [mat, aln, Emat, Ealn,] OFF
 _CHG = 7          # with_stats carry rows (stats-free carry is 3)
 
 
-def _kernel_g(tlen_ref, ismatch_ref, moves_ref, fin_ref,
-              ch_ref, *, qmax: int, band: int, maxshift: int,
+def _kernel_g(tlen_ref, rows_ref, tpad_ref, moves_ref, fin_ref,
+              ch_ref, wnd_ref, *, qmax: int, band: int, maxshift: int,
               params: AlignParams, with_stats: bool, gblock: int):
     """G-batched banded DP fill: GBLOCK alignments per grid step.
 
@@ -183,20 +178,38 @@ def _kernel_g(tlen_ref, ismatch_ref, moves_ref, fin_ref,
     This halves the select-chain cost vs materializing both views per
     candidate d.
 
-    Per-row scalars d (band shift, 0..maxshift) and live (i <= qlen) are
-    BIT-PACKED into lane 0 of the ismatch input (bits 1-3 and 4; bit 0
-    stays the match indicator on every lane): Mosaic requires lane-dim
-    blocks of 128 (so a (G, ROWBLOCK) scalar block never lowers on real
-    TPU) and dynamic lane slices must be 128-aligned (so a full-lane
-    scalar array can't be sliced per ROWBLOCK chunk either).  Riding the
-    already-aligned ismatch tile costs nothing.
+    Per-row scalars — q[i-1]'s code, d (band shift, 0..maxshift) and
+    live (i <= qlen) — are BIT-PACKED into one int8 per row (bits 0-2,
+    3-5 and 6), broadcast across a (ROWBLOCK, B) tile and read at lane 0:
+    Mosaic requires lane-dim blocks of 128 (so a (G, ROWBLOCK) scalar
+    block never lowers on real TPU) and dynamic lane slices must be
+    128-aligned (so a full-lane scalar array can't be sliced per
+    ROWBLOCK chunk either).
+
+    The match indicators are built here, row by row: lane k of row i
+    compares q[i-1] with tpad[offs[i] + k], the base entering column
+    offs[i] + k.  The kernel holds a 2B-lane window of the problem's
+    padded template, from the offset of the row before its first step,
+    in VMEM scratch and rebuilds it every ``wsteps`` grid steps: in
+    that many steps offsets advance by at most B lanes, so each row's
+    band is a per-problem lane gather of the window at offs[i] - base.
+    A rebuild picks the three B-lane blocks of the template that hold
+    the window (a select over blocks, since the block differs per
+    problem) and gathers the window out of them at the base's
+    remainder.
 
     Inputs (blocks):
-      tlen_ref    (G, 1) int32
-      ismatch_ref (G, ROWBLOCK, B) int32 — bit 0 match; lane 0 carries
-                  d at bits 1-3 and live at bit 4
+      tlen_ref (G, 1) int32
+      rows_ref (G, ROWBLOCK, B) int8 — per row, on every lane: the
+               query code (0-3 a base, NOBASE_Q else) at bits 0-2, d at
+               bits 3-5, live at bit 6
+      tpad_ref (G, TP) int32 — lane j holds the base of column j
+               (t[j-1]; PAD at j = 0, past tmax and for a non-base); the
+               same block for every grid step of a problem block
     Outputs: moves (G, ROWBLOCK, B) uint8; fin (G, 8, B) int32 rows
     0/1/2 = final H/mat/aln bands (mat/aln zero when stats are off).
+    Scratch: ch_ref, the carry; wnd_ref (3, G, B) int32, the template
+    window's two halves and its base offset.
     """
     M, X = params.match, params.mismatch
     O, E = params.gap_open, params.gap_extend
@@ -207,6 +220,12 @@ def _kernel_g(tlen_ref, ismatch_ref, moves_ref, fin_ref,
     r = pl.program_id(1)
     karr = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
     tlen_col = tlen_ref[:, 0:1]                      # (G, 1)
+    lg_b = B.bit_length() - 1                        # B is a power of 2
+    nblk = tpad_ref.shape[1] // B
+    # grid steps one template window serves: offsets advance by at most
+    # ROWBLOCK * maxshift lanes a step, and the window's 2B lanes hold
+    # every row's band while they have advanced by at most B
+    wsteps = max(1, B // (ROWBLOCK * max(maxshift, 1)))
 
     def shift_blk(blk, s):
         """Static lane shift of a carry block: out[..., k] = blk[..., k+s],
@@ -243,16 +262,42 @@ def _kernel_g(tlen_ref, ismatch_ref, moves_ref, fin_ref,
                  else [H0, E0, z])
         ch_ref[:] = jnp.stack(rows0, axis=0)
 
+    def gather(x, idx):
+        """out[g, k] = x[g, idx[g, k]]: a per-problem lane gather."""
+        return jnp.take_along_axis(x, idx, axis=1)
+
+    # ---- template window: tpad[base + k] for k < 2B, base = this row's
+    # offset (the carry's OFF, every lane alike) ----
+    @pl.when(r % wsteps == 0)
+    def _():
+        base = ch_ref[noff]                          # (G, B)
+        blk = base >> lg_b
+        # the blocks blk, blk+1, blk+2 hold the window
+        parts = [tpad_ref[:, m * B:(m + 1) * B] for m in range(3)]
+        for c in range(1, nblk - 2):
+            hit = blk == c
+            parts = [jnp.where(hit, tpad_ref[:, (c + m) * B:(c + m + 1) * B],
+                               p) for m, p in enumerate(parts)]
+        src = (base & (B - 1)) + karr
+        lane = src & (B - 1)
+        first = src < B
+        wnd_ref[0] = jnp.where(first, gather(parts[0], lane),
+                               gather(parts[1], lane))
+        wnd_ref[1] = jnp.where(first, gather(parts[1], lane),
+                               gather(parts[2], lane))
+        wnd_ref[2] = base
+
+    wnd_lo, wnd_hi, wnd_base = wnd_ref[0], wnd_ref[1], wnd_ref[2]
     # int32 throughout: i8 sublane slices hit Mosaic relayout limits
-    packed_tile = ismatch_ref[...].astype(jnp.int32)   # (G, ROWBLOCK, B)
-    ismatch_tile = packed_tile & 1
+    packed_tile = rows_ref[...].astype(jnp.int32)    # (G, ROWBLOCK, B)
     ch = ch_ref[:]
     moves_rows = []
     for s in range(ROWBLOCK):
         i = r * ROWBLOCK + s + 1
         lane0 = packed_tile[:, s, 0:1]               # (G, 1) packed scalars
-        d_col = (lane0 >> 1) & 7
-        live_col = ((lane0 >> 4) & 1) != 0           # (G, 1) bool
+        q_col = lane0 & 7
+        d_col = (lane0 >> 3) & 7
+        live_col = ((lane0 >> 6) & 1) != 0           # (G, 1) bool
 
         # select the (d-1)-shifted view of the shiftable carry rows (the
         # diagonal predecessors), then derive the d view (the vertical
@@ -276,8 +321,16 @@ def _kernel_g(tlen_ref, ismatch_ref, moves_ref, fin_ref,
             Emat_up, Ealn_up = up[4], up[5]
         OFF = ch[noff] + d_col                       # this row's band offset
 
-        im = ismatch_tile[:, s, :]                   # (G, B) int32 0/1
-        sub = X + (M - X) * im
+        # the row's template band out of the window; a non-base never
+        # matches (query non-bases are NOBASE_Q, template ones PAD)
+        widx = OFF - wnd_base + karr                 # in [0, 2B)
+        wlane = widx & (B - 1)
+        tb = jnp.where(widx < B, gather(wnd_lo, wlane),
+                       gather(wnd_hi, wlane))
+        is_match = tb == q_col                       # (G, B) bool
+        sub = jnp.where(is_match, M, X)
+        if with_stats:
+            im = is_match.astype(jnp.int32)
         j = OFF + karr
 
         # E (vertical)
@@ -434,8 +487,11 @@ def _batched_align_impl(
 ):
     B = band if band is not None else params.band
     if maxshift > 7:
-        # d rides lane 0 of the ismatch tile in bits 1-3 (see _kernel_g)
+        # d rides the packed row scalars in bits 3-5 (see _kernel_g)
         raise ValueError(f"maxshift={maxshift} exceeds the 3-bit pack limit")
+    if B & (B - 1):
+        # the template window's lane arithmetic is by shifts and masks
+        raise ValueError(f"band={B} must be a power of two")
     lead = qs.shape[:-1]
     qmax = qs.shape[-1]
     if qmax > PALLAS_MAX_QMAX:
@@ -473,10 +529,18 @@ def _batched_align_impl(
         [jnp.zeros((npad, 1), jnp.int32), offs[:, :-1]], axis=1)
     rows = jnp.arange(1, qmax + 1, dtype=jnp.int32)
     live = (rows[None, :] <= qlens_f[:, None]).astype(jnp.int32)
-    # bit-pack the per-row scalars into lane 0 of the ismatch tile (see
-    # _kernel_g docstring): bit 0 match, bits 1-3 d, bit 4 live
-    aux = (((dmat & 7) << 1) | (live << 4)).astype(jnp.int8)
-    lane_is0 = (jnp.arange(B, dtype=jnp.int32) == 0)[None, None, :]
+    # bit-pack the per-row scalars (see _kernel_g docstring): bits 0-2
+    # q[i-1]'s code, bits 3-5 d, bit 6 live
+    qcode = jnp.where(qs_f < 4, qs_f, NOBASE_Q).astype(jnp.int32)
+    aux = (qcode | ((dmat & 7) << 3) | (live << 6)).astype(jnp.int8)
+    # the padded template, column-indexed, in whole B-lane blocks: the
+    # window's three blocks from the largest offset's (tmax - B + 1)
+    tmax = ts_f.shape[-1]
+    tp = (max(tmax - B + 1, 0) // B + 3) * B
+    tcode = jnp.where(ts_f < 4, ts_f, PAD).astype(jnp.int32)
+    tpad = jnp.concatenate([
+        jnp.full((npad, 1), PAD, jnp.int32), tcode,
+        jnp.full((npad, tp - 1 - tmax), PAD, jnp.int32)], axis=1)
 
     kern = functools.partial(
         _kernel_g, qmax=qmax, band=B, maxshift=maxshift, params=params,
@@ -484,12 +548,11 @@ def _batched_align_impl(
     nb = qmax // ROWBLOCK
     # the fill is issued as up to MAX_CALLS kernel calls, one after the
     # other, over equal runs of gblock-problem blocks (the same grid
-    # steps in the same order as one call), each call's match tile built
-    # just before it.  A 0.1 s profiler session on a v5e that falls
-    # inside one long device operation (a match tile for a whole
-    # 128-row slab took ~0.35 s) records no device event at all; here
-    # each operation covers one run of blocks.  (Kernel calls inside a
-    # lax.map loop are never recorded, so the calls are not looped.)
+    # steps in the same order as one call).  A 0.1 s profiler session on
+    # a v5e that falls inside one long device operation records no device
+    # event at all; here each operation covers one run of blocks.
+    # (Kernel calls inside a lax.map loop are never recorded, so the
+    # calls are not looped.)
     fill = pl.pallas_call(
         kern,
         grid=(per_call // gblock, nb),
@@ -497,6 +560,8 @@ def _batched_align_impl(
             pl.BlockSpec((gblock, 1), lambda i, r: (i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((gblock, ROWBLOCK, B), lambda i, r: (i, r, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((gblock, tp), lambda i, r: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -509,18 +574,18 @@ def _batched_align_impl(
             jax.ShapeDtypeStruct((per_call, qmax, B), jnp.uint8),
             jax.ShapeDtypeStruct((per_call, 8, B), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM(
-            (_CHG if with_stats else 3, gblock, B), jnp.int32)],
+        scratch_shapes=[
+            pltpu.VMEM((_CHG if with_stats else 3, gblock, B), jnp.int32),
+            pltpu.VMEM((3, gblock, B), jnp.int32),
+        ],
         interpret=interpret,
     )
 
     def call(k):
         blk = slice(k, k + per_call)
-        ismatch = jax.vmap(
-            lambda q, t, o: compute_ismatch(q, t, o, B, maxshift)
-        )(qs_f[blk], ts_f[blk], offs[blk])
-        ismatch = jnp.where(lane_is0, ismatch | aux[blk, :, None], ismatch)
-        return fill(tlens_f[blk, None], ismatch)
+        # slices, not indexing, so that no gather enters the program
+        rows_in = jnp.broadcast_to(aux[blk][:, :, None], (per_call, qmax, B))
+        return fill(tlens_f[blk][:, None], rows_in, tpad[blk])
 
     calls = [call(k) for k in range(0, npad, per_call)]
     moves = jnp.concatenate([m for m, _ in calls])[:n]
@@ -530,21 +595,26 @@ def _batched_align_impl(
     tlens_f = tlens_f[:n]
 
     # final-row extraction (mirrors ops/banded.py global-mode epilogue)
-    off_fin = offs[:, -1]
+    off_fin = offs[:, qmax - 1:].reshape(n)
     laneT = tlens_f - off_fin
     reachable = (laneT >= 0) & (laneT < B)
     lane = jnp.clip(laneT, 0, B - 1)
-    take = jax.vmap(lambda f, l: f[:, l])(fin, lane)  # (n, 8)
+    # the final H/mat/aln bands at each problem's lane, as a masked sum
+    # (one lane is kept)
+    at_lane = jnp.arange(B, dtype=jnp.int32) == lane[:, None, None]
+    h_fin, mat_fin, aln_fin = (
+        v.reshape(n) for v in jnp.split(
+            jnp.where(at_lane, fin[:, :3], 0).sum(axis=-1), 3, axis=1))
     zeros = jnp.zeros(lead, jnp.int32)
     res = BandedResult(
-        score=jnp.where(reachable, take[:, 0], NEG).reshape(lead),
+        score=jnp.where(reachable, h_fin, NEG).reshape(lead),
         qb=jnp.zeros(lead, jnp.int32),
         qe=qlens_f.reshape(lead),
         tb=jnp.zeros(lead, jnp.int32),
         te=tlens_f.reshape(lead),
-        aln=jnp.where(reachable, take[:, 2], 0).reshape(lead)
+        aln=jnp.where(reachable, aln_fin, 0).reshape(lead)
         if with_stats else zeros,
-        mat=jnp.where(reachable, take[:, 1], 0).reshape(lead)
+        mat=jnp.where(reachable, mat_fin, 0).reshape(lead)
         if with_stats else zeros,
     )
     moves = moves.reshape(lead + (qmax, B))

@@ -38,11 +38,13 @@ def _random_case(rng, Qmax, Tmax, tmin=40, tspan=160):
     return qs, np.int32(len(q)), ts, np.int32(tl)
 
 
-def _compare(qs, qlens, ts, tlens, params):
-    scan_f = banded.make_batched("global", params, with_moves=True)
+def _compare(qs, qlens, ts, tlens, params, with_stats=True, gblock=None):
+    scan_f = banded.make_batched("global", params, with_moves=True,
+                                 with_stats=with_stats)
     r1, m1, o1 = scan_f(qs, qlens, ts, tlens)
     r2, m2, o2 = banded_pallas.batched_align_global_moves(
-        qs, qlens, ts, tlens, params, interpret=INTERPRET)
+        qs, qlens, ts, tlens, params, interpret=INTERPRET,
+        with_stats=with_stats, gblock=gblock)
     np.testing.assert_array_equal(np.asarray(r1.score), np.asarray(r2.score))
     np.testing.assert_array_equal(np.asarray(r1.mat), np.asarray(r2.mat))
     np.testing.assert_array_equal(np.asarray(r1.aln), np.asarray(r2.aln))
@@ -54,15 +56,108 @@ def _compare(qs, qlens, ts, tlens, params):
             m1[i, :ql], m2[i, :ql], err_msg=f"moves mismatch, problem {i}")
 
 
-def test_bit_exact_random_batch():
-    rng = np.random.default_rng(7)
-    Qmax, Tmax, N = 256, 256, 5
-    cases = [_random_case(rng, Qmax, Tmax) for _ in range(N)]
-    qs = np.stack([c[0] for c in cases])
-    qlens = np.array([c[1] for c in cases], np.int32)
-    ts = np.stack([c[2] for c in cases])
-    tlens = np.array([c[3] for c in cases], np.int32)
-    _compare(qs, qlens, ts, tlens, AlignParams())
+def _stack(cases):
+    return (np.stack([c[0] for c in cases]),
+            np.array([c[1] for c in cases], np.int32),
+            np.stack([c[2] for c in cases]),
+            np.array([c[3] for c in cases], np.int32))
+
+
+def _case_random(rng):
+    return _stack([_random_case(rng, 256, 256) for _ in range(5)])
+
+
+def _case_maxshift_edge(rng):
+    """Bands that advance by maxshift on every row (template 4x the
+    query and more), so that a template window is used up to its last
+    lane before the kernel rebuilds it; and one that stops at tcap."""
+    Qmax, Tmax, ms = 128, 768, 4
+    cases = []
+    for ql, tl in ((128, 128 * ms + 127), (128, Tmax), (96, 96 * ms + 127),
+                   (120, 300)):
+        q = rng.integers(0, 4, Qmax).astype(np.uint8)
+        q[ql:] = banded.PAD
+        t = np.full(Tmax, banded.PAD, np.uint8)
+        t[:tl] = rng.integers(0, 4, tl)
+        t[:ql] = q[:ql]            # some diagonal signal near the start
+        cases.append((q, np.int32(ql), t, np.int32(tl)))
+    return _stack(cases)
+
+
+def _case_tlen_edges(rng):
+    """tlen < B (the band is the whole template, offsets stay 0) and
+    tlen == Tmax (the last offsets sit at tlen - B + 1)."""
+    Qmax, Tmax = 256, 256
+    cases = []
+    for tl in (1, 50, 127, 128, 129, Tmax, Tmax):
+        tpl = rng.integers(0, 4, tl).astype(np.uint8)
+        q = synth.mutate(rng, tpl, 0.03, 0.05, 0.05)[:Qmax]
+        qs = np.full(Qmax, banded.PAD, np.uint8)
+        qs[:len(q)] = q
+        ts = np.full(Tmax, banded.PAD, np.uint8)
+        ts[:tl] = tpl
+        cases.append((qs, np.int32(len(q)), ts, np.int32(tl)))
+    return _stack(cases)
+
+
+def _case_nonbase(rng):
+    """N (4) and PAD (5) codes inside both sequences never match, and
+    whatever lies in the template past tlen is compared as it is."""
+    qs, qlens, ts, tlens = _case_random(rng)
+    for a, lens in ((qs, qlens), (ts, tlens)):
+        for i, n in enumerate(lens):
+            at = rng.integers(0, int(n), max(1, int(n) // 8))
+            a[i, at] = rng.choice([4, 5], len(at))
+    ts[0, tlens[0]:] = rng.integers(0, 6, ts.shape[1] - tlens[0])
+    return qs, qlens, ts, tlens
+
+
+def _case_pad_problems(rng):
+    """Pad problems (qlen 0, tlen 0) among live ones, in a batch that is
+    no whole number of problem blocks."""
+    qs, qlens, ts, tlens = _stack(
+        [_random_case(rng, 128, 128, tmin=40, tspan=60) for _ in range(10)])
+    for i in (0, 4, 9):
+        qs[i], ts[i] = banded.PAD, banded.PAD
+        qlens[i] = tlens[i] = 0
+    return qs, qlens, ts, tlens
+
+
+def _case_qmax4096(rng):
+    """The largest qmax the kernel takes, over two problem blocks: the
+    window moves through every block of a 4 kb template."""
+    Qmax, Tmax, N = 4096, 4096, 9
+    cases = []
+    for i in range(N):
+        tl = int(rng.integers(3000, Tmax + 1)) if i else Tmax
+        tpl = rng.integers(0, 4, tl).astype(np.uint8)
+        q = synth.mutate(rng, tpl, 0.02, 0.05, 0.05)[:Qmax]
+        qs = np.full(Qmax, banded.PAD, np.uint8)
+        qs[:len(q)] = q
+        ts = np.full(Tmax, banded.PAD, np.uint8)
+        ts[:tl] = tpl
+        cases.append((qs, np.int32(len(q)), ts, np.int32(tl)))
+    return _stack(cases)
+
+
+@pytest.mark.parametrize("case,seed,with_stats,gblock", [
+    (_case_random, 7, True, None),
+    (_case_random, 8, False, 16),
+    (_case_maxshift_edge, 9, True, 8),
+    (_case_maxshift_edge, 10, False, 16),
+    (_case_tlen_edges, 11, False, 8),
+    (_case_tlen_edges, 12, True, 16),
+    (_case_nonbase, 13, True, 8),
+    (_case_nonbase, 14, False, 16),
+    (_case_pad_problems, 15, False, 8),
+    (_case_qmax4096, 16, False, 8),
+], ids=lambda v: v.__name__[6:] if callable(v) else None)
+def test_bit_exact_random_batch(case, seed, with_stats, gblock):
+    """v1 against the scan, byte for byte: the random batch and the
+    template-window edges (see each case)."""
+    qs, qlens, ts, tlens = case(np.random.default_rng(seed))
+    _compare(qs, qlens, ts, tlens, AlignParams(), with_stats=with_stats,
+             gblock=gblock)
 
 
 @pytest.mark.slow  # ~15s edge sweep; bit_exact_random_batch and
@@ -468,24 +563,62 @@ def test_fill_selection_rejects_unknown_impl(monkeypatch):
         star.banded_impl_effective(2048)
 
 
-@pytest.mark.parametrize("n,calls,grid", [
+FILL_SHAPES = pytest.mark.parametrize("n,calls,grid", [
     (8, 1, 1),        # one gblock block
     (24, 3, 1),       # a call per block
     (128, 16, 1),     # a 128-row slab
     (260, 11, 3),     # at most MAX_CALLS calls of 3 blocks each
 ])
-def test_fill_is_issued_per_block(n, calls, grid):
-    """The fill runs as at most MAX_CALLS kernel calls over equal runs of
-    whole GBLOCK blocks, each with its own match tile, so that no single
-    device operation spans a whole slab."""
-    assert banded_pallas.MAX_CALLS == 16
-    qmax = 64
+FILL_QMAX = 64
+
+
+def _fill_jaxpr(n):
     S = jax.ShapeDtypeStruct
-    jaxpr = str(jax.make_jaxpr(
+    return jax.make_jaxpr(
         lambda *a: banded_pallas.batched_align_global_moves(
             *a, AlignParams(), with_stats=False, interpret=True))(
-        S((n, qmax), jnp.uint8), S((n,), jnp.int32),
-        S((n, qmax), jnp.uint8), S((n,), jnp.int32)))
+        S((n, FILL_QMAX), jnp.uint8), S((n,), jnp.int32),
+        S((n, FILL_QMAX), jnp.uint8), S((n,), jnp.int32))
+
+
+@FILL_SHAPES
+def test_fill_is_issued_per_block(n, calls, grid):
+    """The fill runs as at most MAX_CALLS kernel calls over equal runs of
+    whole GBLOCK blocks, so that no single device operation spans a
+    whole slab."""
+    assert banded_pallas.MAX_CALLS == 16
+    jaxpr = str(_fill_jaxpr(n))
     assert jaxpr.count("pallas_call[") == calls
-    assert jaxpr.count(f"grid=({grid}, {qmax // banded_pallas.ROWBLOCK})") \
-        == calls
+    assert jaxpr.count(
+        f"grid=({grid}, {FILL_QMAX // banded_pallas.ROWBLOCK})") == calls
+
+
+def _primitives_outside_kernels(jaxpr, counts):
+    """Primitive counts of a jaxpr and the jaxprs nested in it, leaving
+    out the bodies of its pallas_calls."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        counts[name] = counts.get(name, 0) + 1
+        if name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    _primitives_outside_kernels(sub, counts)
+    return counts
+
+
+@FILL_SHAPES
+def test_fill_builds_no_match_tile(n, calls, grid):
+    """The match indicators are built inside the kernel: outside its
+    calls the fill holds no gather, no dynamic slice and no loop but
+    compute_offsets' one scan."""
+    counts = _primitives_outside_kernels(_fill_jaxpr(n).jaxpr, {})
+    assert counts["pallas_call"] == calls
+    assert counts.get("scan") == 1
+    for name in ("gather", "dynamic_slice", "while"):
+        assert name not in counts, f"{name}: {counts}"
